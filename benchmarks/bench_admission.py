@@ -32,6 +32,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_dims, init_params
 from repro.serve import Engine, Request
 
@@ -106,6 +107,7 @@ def run_one(cfg, params, path: str, n_req: int, blocks, max_batch: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--requests", type=int, default=16)

@@ -46,6 +46,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from repro.configs import ARCHS, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_dims, init_params
 from repro.serve import Engine, EngineConfig, Request
 
@@ -148,6 +149,7 @@ def run_one(cfg, params, prompts, cache: bool, max_new: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--requests", type=int, default=16)

@@ -31,6 +31,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_dims, init_params
 from repro.serve import Engine, EngineConfig, Request
 
@@ -91,6 +92,7 @@ def admit_one(cfg, params, mode: str, prompt_blocks: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b")
     # 1024-token prompt, 32-token chunks: long enough that the recompute

@@ -40,6 +40,7 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from repro.configs import ARCHS, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_dims, init_params
 from repro.runtime import ResilientServe, ServeFaultInjector
 from repro.serve import Engine, EngineConfig, Request
@@ -153,6 +154,7 @@ def measure_replay_vs_cold(cfg, params, n_req, max_batch, max_new,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--n-req", type=int, default=12)

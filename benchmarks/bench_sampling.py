@@ -31,6 +31,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from common import summarize_times  # noqa: E402
 
 from repro.configs import ARCHS, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_dims, init_params
 from repro.serve import Engine, EngineConfig, Request, SamplingParams
 
@@ -89,6 +90,7 @@ def run_batch(cfg, params, max_batch: int, warmup: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--batches", default="2,4")

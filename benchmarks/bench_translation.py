@@ -58,9 +58,13 @@ def run() -> list:
         "radix": jax.jit(lambda v: translate_radix(None, rtab, v)),
         "ech": jax.jit(lambda v: translate_ech(est, v)),
         "pom_tlb": jax.jit(lambda v: translate_pom(pst, rtab, v)),
-        "utopia_rsw_kernel": lambda v: utopia_rsw(
-            v, ts.rest.tar, ts.rest.sf, ff),
     }
+    # the Pallas kernel lowers through Mosaic on a TPU; elsewhere it runs
+    # in the Pallas interpreter, and the row says so
+    interpret = jax.default_backend() != "tpu"
+    backends["utopia_rsw_kernel" + ("_interpret" if interpret else "")] = (
+        lambda v: utopia_rsw(v, ts.rest.tar, ts.rest.sf, ff,
+                             interpret=interpret))
     rows = []
     baseline_acc = None
     for name, fn in backends.items():

@@ -295,14 +295,6 @@ def analyze_costs(hlo: str) -> Dict:
             "top_computations": top}
 
 
-def normalize_cost_analysis(ca) -> Dict:
-    """compiled.cost_analysis() returns a dict on current jax but a
-    one-element list of dicts on older releases; normalize to a dict."""
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def loop_corrected_costs(compiled, hlo: Optional[str] = None) -> Dict:
     """cost_analysis with while-loop bodies re-weighted by trip count.
 
@@ -312,7 +304,7 @@ def loop_corrected_costs(compiled, hlo: Optional[str] = None) -> Dict:
     robust path (used by the roofline) is analytic-per-layer x L,
     cross-checked against this.
     """
-    ca = normalize_cost_analysis(compiled.cost_analysis())
+    ca = compiled.cost_analysis() or {}
     if hlo is None:
         hlo = compiled.as_text()
     comps = _split_computations(hlo)

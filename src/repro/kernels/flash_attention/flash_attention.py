@@ -66,8 +66,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_scr, l_scr, *,
 
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, q_tile: int = 128,
-                           kv_tile: int = 128, interpret: bool = True):
-    """q (B,S,H,D); k/v (B,S,KV,D) -> (B,S,H,D)."""
+                           kv_tile: int = 128, interpret: bool):
+    """q (B,S,H,D); k/v (B,S,KV,D) -> (B,S,H,D).
+
+    ``interpret`` has no default: the CPU tests pass True (the Pallas
+    interpreter), the chip path False (Mosaic)."""
     B, Sq, H, D = q.shape
     Skv, KV = k.shape[1], k.shape[2]
     g = H // KV

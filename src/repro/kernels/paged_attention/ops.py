@@ -15,13 +15,14 @@ from .ref import paged_attention_ref, normalize
                                              "use_kernel", "combine_axes"))
 def paged_attention(q, k_pool, v_pool, slots, ctx_len, *,
                     tok_offset: int = 0, tok_stride: int = 1,
-                    block_tokens=None, interpret: bool = True,
+                    block_tokens=None, interpret: bool,
                     use_kernel: bool = True, combine_axes=()):
     """Decode attention over translated KV blocks.
 
     ``combine_axes``: mesh axis names to psum-combine partial softmax
     results over (flash-decoding across token/slot shards).  Empty outside
-    shard_map.
+    shard_map.  ``interpret`` has no default: True runs the Pallas
+    interpreter (CPU tests), False lowers the kernel through Mosaic (TPU).
     Returns normalized output (B, H, D).
     """
     fn = paged_attention_pallas if use_kernel else paged_attention_ref

@@ -12,12 +12,14 @@ from .ref import rsw_ref
 @functools.partial(jax.jit, static_argnames=("hash_name", "tile", "interpret",
                                              "use_kernel"))
 def utopia_rsw(vpns, tar, sf, flex_flat, *, hash_name: str = "modulo",
-               tile: int = 128, interpret: bool = True,
+               tile: int = 128, interpret: bool,
                use_kernel: bool = True):
     """Hybrid translate a batch of vpns.
 
     Returns (slot, in_rest, mapped) int32 arrays of shape ``vpns.shape``.
     ``use_kernel=False`` dispatches to the pure-jnp oracle (CPU fast path).
+    ``interpret`` has no default: True runs the Pallas interpreter (CPU
+    tests), False lowers the kernel through Mosaic (TPU).
     """
     shape = vpns.shape
     flat = vpns.reshape(-1)

@@ -18,7 +18,11 @@ def rsw_ref(vpns: jax.Array, tar: jax.Array, sf: jax.Array,
     hit = jnp.any(eq, axis=-1) & (counters > 0)
     way = jnp.argmax(eq, axis=-1).astype(jnp.int32)
     rest_slot = set_idx * assoc + jnp.where(hit, way, 0)
-    flex_slot = flex_flat[vpns]
+    # a vpn outside the flex table reads as unmapped, as in the kernel
+    n_flex = flex_flat.shape[0]
+    in_table = (vpns >= 0) & (vpns < n_flex)
+    flex_slot = jnp.where(in_table, flex_flat[jnp.clip(vpns, 0, n_flex - 1)],
+                          -1)
     slot = jnp.where(hit, rest_slot, flex_slot)
     mapped = hit | (flex_slot >= 0)
     return (jnp.where(mapped, slot, -1).astype(jnp.int32),

@@ -1,8 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the
-# device count at first initialization).  Placeholder CPU devices stand in
-# for the production TPU mesh: 16x16 = one pod, 2x16x16 = two pods.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the device
+# count at first initialization).  Placeholder CPU devices stand in for the
+# production TPU mesh: 16x16 = one pod, 2x16x16 = two pods.  The CPU pin
+# is inherited by every child cell process (``--all``): a dry run never
+# takes an accelerator, so it cannot contend for a chip that belongs to a
+# serving process.
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this builds the real step function (train_step /
@@ -215,7 +219,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t_compile = time.perf_counter() - t0
 
     mem = compiled.memory_analysis()
-    ca = hlo_analysis.normalize_cost_analysis(compiled.cost_analysis())
+    ca = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     coll = hlo_analysis.analyze_collectives(hlo)
     costs = hlo_analysis.loop_corrected_costs(compiled, hlo)

@@ -1,6 +1,7 @@
 """Serving launcher: continuous batching over the hybrid KV pool.
 
     PYTHONPATH=src python -m repro.launch.serve --arch granite-8b \\
+        [--reduced | --layers 8 --dtype bfloat16] \\
         --requests 8 --max-new 16 [--mode hybrid|flexible_only|restrictive_only] \\
         [--prefill-budget 128] [--scheduler fifo|spf|priority] \\
         [--temperature 0.8 --top-k 40 --top-p 0.95 --seed 0] \\
@@ -24,19 +25,64 @@ additionally streams every per-step event to a JSONL file.  All run and
 per-request latencies come from the logger's monotonic clock
 (``time.perf_counter`` — wall-clock ``time.time`` is NTP-step-prone),
 so the dashboard and the printout cannot disagree.
+
+``--reduced`` is the CPU path (tiny widths, for tests and examples).
+Without it the model runs at its published widths; ``--layers N`` cuts
+only the depth, and ``--dtype`` sets the weights, activations and KV pool
+together.  ``load_model`` and ``make_engine`` are the one engine set-up
+that this launcher and ``chip_smoke.py`` share.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config, reduced as reduce_cfg
+from repro.configs import ArchConfig, get_config, reduced as reduce_cfg
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_dims, init_params
 from repro.serve import (Engine, EngineConfig, JsonlSink, MetricsLogger,
                          Request, SamplingParams)
+
+DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
+
+
+def load_model(arch: str, *, reduced: bool = False,
+               layers: Optional[int] = None, dtype: str = "float32"):
+    """(config, random params) for ``arch``.
+
+    ``reduced`` shrinks every width (the CPU smoke configuration);
+    ``layers`` cuts only the depth and keeps the published widths.
+    Params are made from seed 0 directly in ``dtype``.
+    """
+    cfg = get_config(arch)
+    if reduced:
+        cfg = reduce_cfg(cfg)
+    if layers is not None:
+        if not 1 <= layers <= cfg.num_layers:
+            raise ValueError(f"--layers {layers} outside [1, "
+                             f"{cfg.num_layers}] for {cfg.name}")
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    params = init_params(jax.random.PRNGKey(0), cfg,
+                         model_dims(cfg, tp=1), dtype=DTYPES[dtype])
+    return cfg, params
+
+
+def make_engine(cfg: ArchConfig, params, *, max_batch: int,
+                max_seq_len: int, dtype: str = "float32",
+                mode: str = "hybrid", **options) -> Engine:
+    """The serving engine as the launchers build it: finished requests
+    release their slots, and ``dtype`` sets the activations and the KV
+    pool (the params already carry it).  ``options`` are further
+    ``EngineConfig`` fields."""
+    return Engine(cfg, params, EngineConfig(
+        max_batch=max_batch, max_seq_len=max_seq_len, mode=mode,
+        dtype=DTYPES[dtype], auto_release=True, **options))
 
 
 def main() -> None:
@@ -111,7 +157,13 @@ def main() -> None:
                          "(comma list) — with --snapshot-every the "
                          "supervisor restores and replays; streams are "
                          "bit-identical to an uncrashed run")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny widths (the CPU path); default: the "
+                         "published widths")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N layers (widths unchanged)")
+    ap.add_argument("--dtype", default="float32", choices=sorted(DTYPES),
+                    help="weights, activations and KV pool")
     args = ap.parse_args()
     if args.snapshot_dir is not None and args.snapshot_every == 0:
         args.snapshot_every = 10
@@ -122,10 +174,9 @@ def main() -> None:
     logger = MetricsLogger(sinks)
     show_metrics = args.metrics is not None
 
-    cfg = reduce_cfg(get_config(args.arch)) if args.reduced \
-        else get_config(args.arch)
-    dims = model_dims(cfg, tp=1)
-    params = init_params(jax.random.PRNGKey(0), cfg, dims)
+    enable_compile_cache()
+    cfg, params = load_model(args.arch, reduced=args.reduced,
+                             layers=args.layers, dtype=args.dtype)
     bs = cfg.kv_block_size
     S = (args.prompt_blocks + args.shared_prefix_blocks) * bs
     # no speculative headroom: a verify window overrunning the last KV
@@ -136,9 +187,10 @@ def main() -> None:
         from repro.runtime import ServeFaultInjector
         injector = ServeFaultInjector(crash_at=[
             (int(s), "pre") for s in args.crash_at.split(",")])
-    eng = Engine(cfg, params, EngineConfig(
-        max_batch=args.max_batch,
+    eng = make_engine(
+        cfg, params, max_batch=args.max_batch,
         max_seq_len=S + cfg.frontend_tokens + args.max_new + bs,
+        dtype=args.dtype,
         # a shared-prefix demo needs a bounded admission budget: with
         # room for every prompt in round 1, followers admit before
         # request 0's blocks are published (insertion is post-dispatch)
@@ -146,7 +198,7 @@ def main() -> None:
         mode=args.mode, prefill_budget=(
             S if args.prefill_budget is None
             and args.shared_prefix_blocks > 0 else args.prefill_budget),
-        auto_release=True, scheduler=args.scheduler,
+        scheduler=args.scheduler,
         prefill_mode=args.prefill_mode,
         spec_decode="ngram" if args.spec_decode else None,
         num_draft_tokens=args.num_draft_tokens,
@@ -154,7 +206,7 @@ def main() -> None:
         metrics=logger,
         fault_injector=injector,
         mesh_shape=((args.data, args.model)
-                    if (args.data, args.model) != (1, 1) else None)))
+                    if (args.data, args.model) != (1, 1) else None))
     sup = None
     if args.snapshot_every > 0:
         from repro.runtime import ResilientServe

@@ -206,5 +206,7 @@ def attention(q, k, v, *, impl: str = "dense", causal: bool = True,
             kv_chunk=kv_chunk, triangular_schedule=triangular_schedule)
     if impl == "pallas":
         from repro.kernels.flash_attention import ops as fa_ops
-        return fa_ops.flash_attention(q, k, v, causal=causal)
+        # the Mosaic-lowered TPU kernel; the interpreter is for tests only
+        return fa_ops.flash_attention(q, k, v, causal=causal,
+                                      interpret=False)
     raise ValueError(f"unknown attention impl {impl!r}")

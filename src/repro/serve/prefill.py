@@ -341,11 +341,11 @@ def make_prefix_prefill_step(cfg: ArchConfig, dims: ModelDims,
             elif spec.use_kernels:
                 from repro.kernels.paged_attention.paged_attention import (
                     paged_attention_pallas)
-                # interpret mode, stated explicitly: lowering the Pallas
-                # kernels non-interpret on real TPU is the open ROADMAP
-                # item shared with the decode/RSW kernels
-                pool = paged_attention_pallas(q, kp_l, vp_l, prefix_slots,
-                                              prefix_ctx, interpret=True)
+                # the Mosaic-lowered TPU kernel; the interpreter is for
+                # the kernel tests only
+                pool = paged_attention_pallas(
+                    q, kp_l, vp_l, prefix_slots, prefix_ctx,
+                    interpret=False)
             else:
                 pool = paged_attention_ref(q, kp_l, vp_l, prefix_slots,
                                            prefix_ctx)

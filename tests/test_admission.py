@@ -213,14 +213,22 @@ def test_eos_terminates_early_and_releases(setup):
     _drain(eng)
     assert len(probe.generated) == 8
 
+    # the eos must be a token the stream has not produced before: random
+    # weights repeat ids, and an earlier occurrence would (rightly) stop
+    # the stream there
+    gen = probe.generated
+    stop = next((i for i in range(1, len(gen)) if gen[i] not in gen[:i]),
+                None)
+    assert stop is not None, f"stream {gen} repeats a single token"
     eng2 = Engine(cfg, params, max_batch=2, max_seq_len=6 * bs,
                   auto_release=True)
     r = Request(seq_id=0, prompt=prompt, max_new_tokens=8,
-                eos_token=probe.generated[2])
+                eos_token=gen[stop])
     eng2.add_request(r)
     _drain(eng2)
     assert r.done
-    assert list(r.generated) == probe.generated[:3]   # stopped ON the eos
+    assert list(r.generated) == gen[:stop + 1]        # stopped ON the eos
+    assert r._engine_state.finish_reason == "stop"
     assert 0 in eng2.finished and 0 not in eng2.requests
     assert not eng2._slot_of                          # slot freed
     eng2.manager.check_invariants()

@@ -127,7 +127,7 @@ def test_rsw_kernel_equals_ref_on_random_tables(vpns):
     sf = (tar != 0).sum(axis=1).astype(np.int32)
     flex = rng.randint(-1, 64, size=512).astype(np.int32)
     out_k = utopia_rsw(jnp.asarray(vpns, jnp.int32), jnp.asarray(tar),
-                       jnp.asarray(sf), jnp.asarray(flex))
+                       jnp.asarray(sf), jnp.asarray(flex), interpret=True)
     out_r = rsw_ref(jnp.asarray(vpns, jnp.int32), jnp.asarray(tar),
                     jnp.asarray(sf), jnp.asarray(flex))
     for a, b in zip(out_k, out_r):

@@ -1,5 +1,7 @@
 """Per-kernel validation: interpret-mode Pallas vs pure-jnp oracles,
 swept over shapes and dtypes (assignment requirement)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,7 +39,7 @@ class TestRSWKernel:
         ff = ts.flex.table.reshape(-1)
         vpns = jnp.arange(16 * 32, dtype=jnp.int32)
         got = utopia_rsw(vpns, ts.rest.tar, ts.rest.sf, ff,
-                         hash_name=hash_name)
+                         hash_name=hash_name, interpret=True)
         want = rsw_ref(vpns, ts.rest.tar, ts.rest.sf, ff,
                        hash_name=hash_name)
         for a, b in zip(got, want):
@@ -53,7 +55,8 @@ class TestRSWKernel:
         ts = m.device_state()
         ff = ts.flex.table.reshape(-1)
         vpns = jnp.arange(100, dtype=jnp.int32)   # not a tile multiple
-        got = utopia_rsw(vpns, ts.rest.tar, ts.rest.sf, ff, tile=tile)
+        got = utopia_rsw(vpns, ts.rest.tar, ts.rest.sf, ff, tile=tile,
+                         interpret=True)
         want = rsw_ref(vpns, ts.rest.tar, ts.rest.sf, ff)
         for a, b in zip(got, want):
             npt.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -81,7 +84,7 @@ class TestRSWKernel:
         queries = big + [v + 1 for v in big] + [v - 1 for v in big] + [0, 7]
         vpns = jnp.asarray(queries, jnp.int32)
         got = utopia_rsw(vpns, jnp.asarray(tar), jnp.asarray(sf),
-                         jnp.asarray(flex))
+                         jnp.asarray(flex), interpret=True)
         want = rsw_ref(vpns, jnp.asarray(tar), jnp.asarray(sf),
                        jnp.asarray(flex))
         for a, b in zip(got, want):
@@ -92,6 +95,23 @@ class TestRSWKernel:
         assert not np.asarray(got[1][n:3 * n]).any(), \
             "rounded-tag neighbours must miss"
 
+    def test_out_of_range_vpn_unmapped(self):
+        """A vpn past the end of the flex table reads as unmapped in both
+        the kernel and the oracle, even when the table's last entry is
+        mapped (a clamped gather would return it)."""
+        m = _populated_manager()
+        ts = m.device_state()
+        ff = ts.flex.table.reshape(-1).at[-1].set(7)
+        n = ff.shape[0]
+        vpns = jnp.asarray([0, n - 1, n, n + 5, 4 * n], jnp.int32)
+        got = utopia_rsw(vpns, ts.rest.tar, ts.rest.sf, ff, interpret=True)
+        want = rsw_ref(vpns, ts.rest.tar, ts.rest.sf, ff)
+        for a, b in zip(got, want):
+            npt.assert_array_equal(np.asarray(a), np.asarray(b))
+        hit = np.asarray(got[1]).astype(bool)
+        assert not hit[1] and int(got[0][1]) == 7
+        assert (np.asarray(got[0])[2:][~hit[2:]] == -1).all()
+
     def test_host_agreement(self):
         m = _populated_manager()
         ts = m.device_state()
@@ -100,7 +120,7 @@ class TestRSWKernel:
             for b in range(20):
                 vpn = m.cfg.vpn(m.seq_slot(sid), b)
                 got = utopia_rsw(jnp.array([vpn], jnp.int32), ts.rest.tar,
-                                 ts.rest.sf, ff)
+                                 ts.rest.sf, ff, interpret=True)
                 assert int(got[0][0]) == m.lookup(sid, b)[0]
 
 
@@ -117,7 +137,8 @@ class TestFlashKernel:
         q = jax.random.normal(ks[0], (B, S, H, D), dtype)
         k = jax.random.normal(ks[1], (B, S, KV, D), dtype)
         v = jax.random.normal(ks[2], (B, S, KV, D), dtype)
-        out = flash_attention(q, k, v, causal=causal, q_tile=64, kv_tile=64)
+        out = flash_attention(q, k, v, causal=causal, q_tile=64, kv_tile=64,
+                              interpret=True)
         ref = dense_attention(q, k, v, causal=causal)
         tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
         npt.assert_allclose(np.asarray(out, np.float32),
@@ -139,7 +160,8 @@ class TestPagedAttentionKernel:
         slots = slots.at[0, nblk // 2].set(-1)          # hole
         ctx = jnp.asarray(np.random.RandomState(0).randint(
             1, bs * nblk, B), jnp.int32)
-        out_k = paged_attention(q, kp, vp, slots, ctx, use_kernel=True)
+        out_k = paged_attention(q, kp, vp, slots, ctx, use_kernel=True,
+                                interpret=True)
         o, m, l = paged_attention_ref(q, kp, vp, slots, ctx)
         npt.assert_allclose(np.asarray(out_k), np.asarray(normalize(o, l)),
                             rtol=2e-5, atol=2e-5)
@@ -230,6 +252,33 @@ class TestPagedAttentionKernel:
             npt.assert_array_equal(np.asarray(l1),
                                    np.asarray(want[2][:, i]))
 
+    @pytest.mark.parametrize("Q", [12, 76])
+    @pytest.mark.parametrize("per_query", [False, True],
+                             ids=["row_extent", "per_query_extent"])
+    def test_query_tiles_with_padding_vs_ref(self, per_query, Q):
+        """At 32 heads the kernel scores queries 8 at a time and puts up
+        to 64 in one query block: Q = 12 runs two tiles in one block, the
+        second padded; Q = 76 runs two blocks of five tiles.  Both extent
+        forms must match the oracle on every real query."""
+        B, H, KV, D, bs, nblk, nslots = 2, 32, 8, 16, 8, 4, 32
+        ks = jax.random.split(jax.random.PRNGKey(3), 4)
+        q = jax.random.normal(ks[0], (B, Q, H, D))
+        kp = jax.random.normal(ks[1], (nslots, bs, KV, D))
+        vp = jax.random.normal(ks[2], (nslots, bs, KV, D))
+        slots = jax.random.randint(ks[3], (B, nblk), 0, nslots)
+        base = np.array([5, bs * 2])
+        ctx = (base[:, None] + 1 + np.arange(Q)[None, :]) if per_query \
+            else base
+        ctx = jnp.asarray(ctx, jnp.int32)
+        from repro.kernels.paged_attention.paged_attention import (
+            paged_attention_pallas)
+        got = paged_attention_pallas(q, kp, vp, slots, ctx, interpret=True)
+        want = paged_attention_ref(q, kp, vp, slots, ctx)
+        for a, b in zip(got, want):
+            assert a.shape == b.shape
+            npt.assert_allclose(np.asarray(a), np.asarray(b),
+                                rtol=2e-5, atol=2e-5)
+
     def test_per_query_ctx_zero_extent_column_drops(self):
         """A query column with extent 0 yields l == 0 (dropped exactly by
         any downstream flash-decoding combine)."""
@@ -242,7 +291,8 @@ class TestPagedAttentionKernel:
         ctx_q = jnp.asarray([[0, 5, 9]], jnp.int32)
         from repro.kernels.paged_attention.paged_attention import (
             paged_attention_pallas)
-        for fn in (paged_attention_ref, paged_attention_pallas):
+        pallas = functools.partial(paged_attention_pallas, interpret=True)
+        for fn in (paged_attention_ref, pallas):
             o, m, l = fn(q, kp, vp, slots, ctx_q)
             npt.assert_array_equal(np.asarray(l)[:, 0], 0.0)
             npt.assert_array_equal(
@@ -262,7 +312,8 @@ class TestPagedAttentionKernel:
         ctx = jnp.asarray([13, 27], jnp.int32)
         from repro.kernels.paged_attention.paged_attention import (
             paged_attention_pallas)
-        for fn in (paged_attention_ref, paged_attention_pallas):
+        pallas = functools.partial(paged_attention_pallas, interpret=True)
+        for fn in (paged_attention_ref, pallas):
             o3, m3, l3 = fn(q, kp, vp, slots, ctx)
             o4, m4, l4 = fn(q[:, None], kp, vp, slots, ctx)
             npt.assert_array_equal(np.asarray(o3), np.asarray(o4[:, 0]))
