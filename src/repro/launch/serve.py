@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ArchConfig, get_config, reduced as reduce_cfg
-from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.compile_cache import enable_compile_cache, key_on_metadata
 from repro.models import model_dims, init_params
 from repro.serve import (Engine, EngineConfig, JsonlSink, MetricsLogger,
                          Request, SamplingParams)
@@ -79,7 +79,16 @@ def make_engine(cfg: ArchConfig, params, *, max_batch: int,
     """The serving engine as the launchers build it: finished requests
     release their slots, and ``dtype`` sets the activations and the KV
     pool (the params already carry it).  ``options`` are further
-    ``EngineConfig`` fields."""
+    ``EngineConfig`` fields.
+
+    Where a persistent compile cache is already on, placed by a caller
+    or by ``JAX_COMPILATION_CACHE_DIR`` rather than by
+    ``enable_compile_cache``, it is keyed on metadata too
+    (``compile_cache.key_on_metadata``), so that the engine's programs
+    never load a cached build without their named scopes.  Without a
+    cache nothing is set."""
+    if jax.config.jax_compilation_cache_dir:
+        key_on_metadata()
     return Engine(cfg, params, EngineConfig(
         max_batch=max_batch, max_seq_len=max_seq_len, mode=mode,
         dtype=DTYPES[dtype], auto_release=True, **options))

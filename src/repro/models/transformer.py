@@ -174,6 +174,7 @@ class FwdOptions:
     moe_groups: int = 1                # MoE dispatch groups (= DP shards)
 
 
+@jax.named_scope("attn")
 def _self_attn(blk, x, cfg, dims, opt, pins, causal=True):
     B, S, _ = x.shape
     pos = jnp.arange(S)[None, :]
@@ -192,6 +193,7 @@ def _self_attn(blk, x, cfg, dims, opt, pins, causal=True):
     return x + pins("act_btd", o), (k, v)
 
 
+@jax.named_scope("ffn")
 def _ffn(blk, x, cfg, dims, opt, pins):
     h = L.rms_norm(x, blk["norm2"].astype(jnp.float32), cfg.norm_eps)
     h = pins("act_full", h)
@@ -361,11 +363,13 @@ def forward(params, batch: Dict[str, jax.Array], cfg: ArchConfig,
         body = jax.checkpoint(body)
     (x, aux), caches = jax.lax.scan(body, (x, _zero_aux()), params["layers"])
 
-    x = L.rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps)
-    if n_front:
-        x = jax.lax.slice_in_dim(x, n_front, x.shape[1], axis=1)
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = L.unembed(head, x, dims.logical_vocab, pins)
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(x, params["final_norm"].astype(jnp.float32),
+                       cfg.norm_eps)
+        if n_front:
+            x = jax.lax.slice_in_dim(x, n_front, x.shape[1], axis=1)
+        head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+        logits = L.unembed(head, x, dims.logical_vocab, pins)
     if cfg.family == "audio" and opt.collect_cache:
         caches = dict(caches)
         caches["enc_out"] = enc_out

@@ -563,6 +563,22 @@ def _paged_attn_shard_local(q, k_new, v_new, kp_l, vp_l,
 # reshapes are identities for the scalar shapes, so the scalar trace is
 # bitwise the pre-refactor one.
 
+@jax.named_scope("kv_pool")
+def _pool_layer(kp, vp, i):
+    """Layer ``i``'s K and V pools, sliced out of the scan-carried
+    (L, slots, ...) pools."""
+    return (jax.lax.dynamic_index_in_dim(kp, i, 0, keepdims=False),
+            jax.lax.dynamic_index_in_dim(vp, i, 0, keepdims=False))
+
+
+@jax.named_scope("kv_pool")
+def _pool_write_back(kp, vp, kp_l, vp_l, i):
+    """Write layer ``i``'s updated K and V pools back into the carry."""
+    return (jax.lax.dynamic_update_index_in_dim(kp, kp_l, i, 0),
+            jax.lax.dynamic_update_index_in_dim(vp, vp_l, i, 0))
+
+
+@jax.named_scope("ffn")
 def decode_ffn(blk, x, cfg: ArchConfig, pins) -> jax.Array:
     """Post-attention FFN sublayer (dense MLP or decode-time MoE)."""
     h = Lmod.rms_norm(x, blk["norm2"].astype(jnp.float32), cfg.norm_eps)
@@ -594,6 +610,7 @@ def decode_cross(blk, x, ck, cv, cfg: ArchConfig, dims: ModelDims, pins
     return x + pins("dec_bd", Lmod.linear(blk["cross"]["o"], o))
 
 
+@jax.named_scope("lm_head")
 def project_logits(params, x, cfg: ArchConfig, dims: ModelDims, pins
                    ) -> jax.Array:
     """Final norm -> (tied) head matmul -> vocab-pad mask -> pins."""
@@ -659,6 +676,7 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
     if sharded and part is None:
         raise ValueError("spec.kv_shards >= 1 requires a Partition")
 
+    @jax.named_scope("attn")
     def attn_sublayer(blk, x, kp_l, vp_l, trans, positions):
         B = x.shape[0]
         q, k, v = qkv_decode(blk, x, positions)
@@ -703,20 +721,22 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
         # ---- the step's single translation dispatch ----------------------
         trans = None
         if n_attn:
-            if sharded:
-                trans = translate_step_sharded(
-                    dstate["tar"], dstate["sf"], dstate["flex"],
-                    positions, spec, part)
-            else:
-                trans = translate_step(dstate["tar"], dstate["sf"],
-                                       dstate["flex"], positions, spec)
-            # group-major view of the active mask gates the KV write
-            G = dstate["tar"].shape[0]
-            if spec.mode == "batch":
-                act_g = act.reshape(G, -1)
-            else:
-                act_g = jnp.broadcast_to(act[None, :], (G, act.shape[0]))
-            trans = trans._replace(w_valid=trans.w_valid & act_g)
+            with jax.named_scope("translate"):
+                if sharded:
+                    trans = translate_step_sharded(
+                        dstate["tar"], dstate["sf"], dstate["flex"],
+                        positions, spec, part)
+                else:
+                    trans = translate_step(dstate["tar"], dstate["sf"],
+                                           dstate["flex"], positions, spec)
+                # group-major view of the active mask gates the KV write
+                G = dstate["tar"].shape[0]
+                if spec.mode == "batch":
+                    act_g = act.reshape(G, -1)
+                else:
+                    act_g = jnp.broadcast_to(act[None, :],
+                                             (G, act.shape[0]))
+                trans = trans._replace(w_valid=trans.w_valid & act_g)
             stats.update(slots=trans.slots, in_rest=trans.in_rest,
                          mapped=trans.mapped, accesses=trans.accesses)
 
@@ -735,12 +755,10 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
                 x, kp, vp = carry
                 blk = xl["blk"]
                 i = xl["idx"]
-                kp_l = jax.lax.dynamic_index_in_dim(kp, i, 0, keepdims=False)
-                vp_l = jax.lax.dynamic_index_in_dim(vp, i, 0, keepdims=False)
+                kp_l, vp_l = _pool_layer(kp, vp, i)
                 x, kp_l, vp_l = attn_sublayer(blk, x, kp_l, vp_l, trans,
                                               positions)
-                kp = jax.lax.dynamic_update_index_in_dim(kp, kp_l, i, 0)
-                vp = jax.lax.dynamic_update_index_in_dim(vp, vp_l, i, 0)
+                kp, vp = _pool_write_back(kp, vp, kp_l, vp_l, i)
                 if fam == "audio":
                     x = cross_sublayer(blk, x, xl["ck"], xl["cv"], None)
                 x = ffn_sublayer(blk, x)
@@ -788,16 +806,10 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
                         ssm_out.append(s)
                         conv_out.append(c)
                     else:
-                        kp_l = jax.lax.dynamic_index_in_dim(
-                            kp, gi, 0, keepdims=False)
-                        vp_l = jax.lax.dynamic_index_in_dim(
-                            vp, gi, 0, keepdims=False)
+                        kp_l, vp_l = _pool_layer(kp, vp, gi)
                         x, kp_l, vp_l = attn_sublayer(
                             blk["attn"], x, kp_l, vp_l, trans, positions)
-                        kp = jax.lax.dynamic_update_index_in_dim(
-                            kp, kp_l, gi, 0)
-                        vp = jax.lax.dynamic_update_index_in_dim(
-                            vp, vp_l, gi, 0)
+                        kp, vp = _pool_write_back(kp, vp, kp_l, vp_l, gi)
                     x = ffn_sublayer(Tmod.hybrid_ffn_select(cfg, blk, i), x)
                 return (x, kp, vp), {"ssm": jnp.stack(ssm_out),
                                      "conv": jnp.stack(conv_out)}
@@ -826,13 +838,14 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
         # work on its hot path.  The default is False so callers that
         # never pass it (dryrun cost cells, direct step tests) keep the
         # pre-sampling argmax trace; the engine passes it explicitly
-        if sample:
-            stats["next_token"] = sample_tokens(
-                logits, dstate["samp_temp"], dstate["samp_topk"],
-                dstate["samp_topp"], dstate["samp_key"], positions)
-        else:
-            stats["next_token"] = jnp.argmax(logits, axis=-1
-                                             ).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            if sample:
+                stats["next_token"] = sample_tokens(
+                    logits, dstate["samp_temp"], dstate["samp_topk"],
+                    dstate["samp_topp"], dstate["samp_key"], positions)
+            else:
+                stats["next_token"] = jnp.argmax(logits, axis=-1
+                                                 ).astype(jnp.int32)
         # only active slots advance: an idle slot's ctx_len must not drift
         # (pre-scheduler it advanced unconditionally, which is why the
         # stale-write bound in translate_step exists)

@@ -115,6 +115,12 @@ from .sampling import GREEDY, SamplingParams, prng_key_data
 from .scheduler import Scheduler, make_scheduler
 from .spec_decode import make_spec_decode_step
 
+# Host spans of the engine's phases in the profiler's own trace (the
+# benchmark attributes device-idle time to them).  With the profiler off
+# a span costs its enter and exit; names are constants, so no string or
+# kwargs is built per step.
+_span = jax.profiler.TraceAnnotation
+
 
 def _pad_pow2(idx: np.ndarray, fill) -> np.ndarray:
     """Pad an index vector to the next power of two (bounded set of XLA
@@ -330,6 +336,11 @@ class RequestState:
     last_step: int = 0
     preempts: int = 0
     resumes: int = 0
+    # queue wait on ``time.perf_counter`` (the clock of ``deadline_at``):
+    # submit -> first prompt chunk admitted.  A resume after preemption
+    # re-enters the queue but leaves both fields as they were.
+    submitted_at: float = 0.0
+    admitted_at: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -783,12 +794,14 @@ class Engine:
             # THIS request's admission attaches the same physical slots
             # the explicit kwargs used to
             _warn_share_kwarg()
-        state = RequestState(request=req, arrival=self._step_count)
+        now = time.perf_counter()
+        state = RequestState(request=req, arrival=self._step_count,
+                             submitted_at=now)
         if req.deadline_ms is not None:
             if req.deadline_ms < 0:
                 raise ValueError(f"deadline_ms must be >= 0, got "
                                  f"{req.deadline_ms}")
-            state.deadline_at = time.perf_counter() + req.deadline_ms / 1e3
+            state.deadline_at = now + req.deadline_ms / 1e3
         object.__setattr__(req, "_engine_state", state)
         self._states[req.seq_id] = state
         self.scheduler.add(req, state.arrival)
@@ -856,7 +869,9 @@ class Engine:
                     # a preempted sequence re-entered through the queue:
                     # resume restores its saved blocks and rows, charging
                     # no prefill budget (nothing is re-forwarded)
-                    if not self._resume_preempted(req, reserved):
+                    with _span("engine.resume", seq_id=req.seq_id):
+                        resumed = self._resume_preempted(req, reserved)
+                    if not resumed:
                         break              # no slot / no capacity yet
                     if req.seq_id not in self._prefilling:
                         continue           # decode-live again this step
@@ -911,6 +926,9 @@ class Engine:
                 # requests, never interleave half-prefilled prompts)
                 self.scheduler.pop(req)
                 self._current = req
+                st_q = self._states[req.seq_id]
+                if st_q.admitted_at is None:
+                    st_q.admitted_at = time.perf_counter()
             budget -= take
             self._prefilling[req.seq_id] = end
             final = end == total
@@ -963,9 +981,12 @@ class Engine:
                 s_pad = bs * _next_pow2(end // bs)
                 buckets[s_pad].append((req, start, end, final))
         for s_pad, grp in sorted(buckets.items()):
-            pending.extend(self._prefill_bucket(grp, s_pad, front))
+            with _span("engine.prefill_dispatch"):
+                pending.extend(self._prefill_bucket(grp, s_pad, front))
         for (s_pad, nblk_buf), grp in sorted(pbuckets.items()):
-            pending.extend(self._prefix_bucket(grp, s_pad, nblk_buf, front))
+            with _span("engine.prefill_dispatch"):
+                pending.extend(self._prefix_bucket(grp, s_pad, nblk_buf,
+                                                   front))
         # ---- publish installed chunks to the prefix cache ---------------
         # POST-dispatch: entries become matchable from the NEXT admission
         # round onward, so a same-round duplicate can never attach a
@@ -1186,6 +1207,10 @@ class Engine:
         st = self._states.get(seq_id)
         if st is None or st.done or seq_id not in self._slot_of:
             raise ValueError(f"sequence {seq_id} is not live")
+        with _span("engine.preempt", seq_id=seq_id):
+            self._swap_out(seq_id, st)
+
+    def _swap_out(self, seq_id: int, st: RequestState) -> None:
         m = self.manager
         slot = self._slot_of[seq_id]
         # pending migration copies must land BEFORE the gather: the
@@ -1776,9 +1801,11 @@ class Engine:
         the commit.  The logger path performs no device operation, so
         logger-on streams are bit-identical to logger-off."""
         if self.metrics is None:
-            return self._step_impl()
+            with _span("engine.step"):
+                return self._step_impl()
         t0 = time.perf_counter()
-        out = self._step_impl()
+        with _span("engine.step"):
+            out = self._step_impl()
         wall = time.perf_counter() - t0
         self.metrics.on_step(self._step_count, wall,
                              self._metrics_counters(),
@@ -1800,24 +1827,23 @@ class Engine:
             self._run_forced_preempts(
                 self._injector.forced_preempts(self._step_count, "pre"))
         fetch = {}
-        pending = self._admit(self.prefill_budget)
+        with _span("engine.admit"):
+            pending = self._admit(self.prefill_budget)
         for r, tok in pending:
             fetch[f"p{r.seq_id}"] = tok
         live = [self._states[sid] for sid, r in self.requests.items()
                 if not self._states[sid].done
                 and sid not in self._prefilling]
-        m = self.manager
-        bs = self.cfg.kv_block_size
         K = self.spec_K
-        nblk = self.spec.max_blocks_per_seq
         if live and self._n_attn_layers:
             # map the blocks this dispatch will write FIRST: an
             # allocation miss may preempt another live sequence (it then
             # drops out of the batch below), so tokens/active are built
             # only after residency settles
-            for st in live:
-                if st.request.seq_id in self._slot_of:
-                    self._ensure_decode_blocks(st)
+            with _span("engine.ensure_blocks"):
+                for st in live:
+                    if st.request.seq_id in self._slot_of:
+                        self._ensure_decode_blocks(st)
             live = [st for st in live
                     if st.request.seq_id in self._slot_of]
         if live:
@@ -1828,8 +1854,10 @@ class Engine:
                 slot = self._slot_of[st.request.seq_id]
                 active[slot] = True
                 tokens[slot] = st.generated[-1]
-            self._apply_copies()
-            self._sync_translation()
+            with _span("engine.apply_copies"):
+                self._apply_copies()
+            with _span("engine.sync_translation"):
+                self._sync_translation()
             # pre-step context snapshot: the telemetry mask below must
             # count the blocks that existed when the step TRANSLATED, and
             # the boundary block only if its allocation actually mapped
@@ -1838,9 +1866,10 @@ class Engine:
             any_sampled = any(not st.request.sampling.is_greedy
                               for st in live)
             step_fn = self._spec_step if K else self._serve_step
-            logits, self.dstate, tstats = step_fn(
-                self.params, self.dstate, jnp.asarray(tokens),
-                jnp.asarray(active), sample=any_sampled)
+            with _span("engine.dispatch"):
+                logits, self.dstate, tstats = step_fn(
+                    self.params, self.dstate, jnp.asarray(tokens),
+                    jnp.asarray(active), sample=any_sampled)
 
             if K:
                 # (B, K+1) window tokens + per-slot emitted counts ride
@@ -1866,58 +1895,33 @@ class Engine:
                     crash(self._step_count, "post")
             return {}
         # ---- the step's ONE device->host fetch --------------------------
-        host = jax.device_get(fetch)
+        with _span("engine.fetch"):
+            host = jax.device_get(fetch)
 
         out: Dict[int, int] = {}
         if live:
             self._ctx_host[:] = host["ctx"]
-            # ---- feed translation telemetry back (PTW-cost tracking) ----
             if want_stats:
-                live_slots = [self._slot_of[st.request.seq_id]
-                              for st in live]
-                live_mask = np.zeros(self.max_batch, bool)
-                live_mask[live_slots] = True
-                # pre-step block counts: blocks covering positions
-                # [0, pos] — [0, pos+K] under speculation, the window the
-                # verify dispatch attends — NOT the post-step ctx, whose
-                # boundary block may not exist yet — further masked by
-                # the device ``mapped`` flag so a failed (swapped)
-                # allocation is not recorded as a flexible walk and fed
-                # to the promoter
-                n_pre = np.minimum((ctx_pre + K) // bs + 1, nblk)
-                valid = (live_mask[:, None]
-                         & (np.arange(nblk)[None, :] < n_pre[:, None])
-                         & np.asarray(host["mapped"][0], bool))
-                vpns = (np.arange(self.max_batch)[:, None] * nblk
-                        + np.arange(nblk)[None, :])
-                in_rest = np.asarray(host["in_rest"][0], bool)
-                m.record_device_stats(vpns[valid], in_rest[valid],
-                                      host["accesses"][0][valid])
-                # the same telemetry, attributed per request: RestSeg
-                # hits vs flexible walks for each sequence's own blocks
-                hits_slot = (valid & in_rest).sum(axis=1)
-                walks_slot = (valid & ~in_rest).sum(axis=1)
-                for st, slot in zip(live, live_slots):
-                    st.rsw_hits += int(hits_slot[slot])
-                    st.flex_walks += int(walks_slot[slot])
-                m.run_promotions()
-                self._apply_copies()
-            if K:
-                self._commit_spec(live, host, ctx_pre, out)
-            else:
-                for st in live:
-                    sid = st.request.seq_id
-                    nxt = int(host["next"][self._slot_of[sid]])
-                    st.generated.append(nxt)
-                    st.new_tokens.append(nxt)
-                    st.last_step = self._step_count
-                    self._tokens_emitted += 1
-                    out[sid] = nxt
-                    self._maybe_finish(st, nxt)
-        for r, _ in pending:
-            nxt = int(host[f"p{r.seq_id}"])
-            self._complete_prefill(r, nxt)
-            out[r.seq_id] = nxt
+                with _span("engine.promotions"):
+                    self._feed_translation_stats(live, host, ctx_pre)
+        with _span("engine.commit"):
+            if live:
+                if K:
+                    self._commit_spec(live, host, ctx_pre, out)
+                else:
+                    for st in live:
+                        sid = st.request.seq_id
+                        nxt = int(host["next"][self._slot_of[sid]])
+                        st.generated.append(nxt)
+                        st.new_tokens.append(nxt)
+                        st.last_step = self._step_count
+                        self._tokens_emitted += 1
+                        out[sid] = nxt
+                        self._maybe_finish(st, nxt)
+            for r, _ in pending:
+                nxt = int(host[f"p{r.seq_id}"])
+                self._complete_prefill(r, nxt)
+                out[r.seq_id] = nxt
         if self._injector is not None:
             # safe point #2: after the commit — under speculation this is
             # the adversarial moment between a window's verify/commit and
@@ -1931,6 +1935,42 @@ class Engine:
             if crash is not None:
                 crash(self._step_count, "post")
         return out
+
+    def _feed_translation_stats(self, live, host, ctx_pre) -> None:
+        """Feed the step's translation telemetry back (PTW-cost
+        tracking): globally to the manager and its promoter, and
+        attributed per request; then apply the promotions' copies."""
+        m = self.manager
+        bs = self.cfg.kv_block_size
+        K = self.spec_K
+        nblk = self.spec.max_blocks_per_seq
+        live_slots = [self._slot_of[st.request.seq_id] for st in live]
+        live_mask = np.zeros(self.max_batch, bool)
+        live_mask[live_slots] = True
+        # pre-step block counts: blocks covering positions [0, pos] —
+        # [0, pos+K] under speculation, the window the verify dispatch
+        # attends — NOT the post-step ctx, whose boundary block may not
+        # exist yet — further masked by the device ``mapped`` flag so a
+        # failed (swapped) allocation is not recorded as a flexible walk
+        # and fed to the promoter
+        n_pre = np.minimum((ctx_pre + K) // bs + 1, nblk)
+        valid = (live_mask[:, None]
+                 & (np.arange(nblk)[None, :] < n_pre[:, None])
+                 & np.asarray(host["mapped"][0], bool))
+        vpns = (np.arange(self.max_batch)[:, None] * nblk
+                + np.arange(nblk)[None, :])
+        in_rest = np.asarray(host["in_rest"][0], bool)
+        m.record_device_stats(vpns[valid], in_rest[valid],
+                              host["accesses"][0][valid])
+        # the same telemetry, attributed per request: RestSeg hits vs
+        # flexible walks for each sequence's own blocks
+        hits_slot = (valid & in_rest).sum(axis=1)
+        walks_slot = (valid & ~in_rest).sum(axis=1)
+        for st, slot in zip(live, live_slots):
+            st.rsw_hits += int(hits_slot[slot])
+            st.flex_walks += int(walks_slot[slot])
+        m.run_promotions()
+        self._apply_copies()
 
     def _commit_spec(self, live, host, ctx_pre, out) -> None:
         """Variable-length commit of the speculative window.
@@ -2235,6 +2275,7 @@ class Engine:
         payload["_shard_swap_out"] = self._shard_swap_out
         payload["_shard_swap_in"] = self._shard_swap_in
         payload["deadline_remaining"] = deadline_remaining
+        payload["clock"] = now
         # the scheduler's back-pointer would drag the whole Engine (and
         # its params) into the blob; strip it around the dump
         sched = self.scheduler
@@ -2297,6 +2338,12 @@ class Engine:
             st = self._states.get(sid)
             if st is not None:
                 st.deadline_at = now + rem
+        # the queue-wait clocks move by the same pause
+        shift = now - host.get("clock", now)
+        for st in self._states.values():
+            st.submitted_at += shift
+            if st.admitted_at is not None:
+                st.admitted_at += shift
         # device state: put the fetched arrays back (with the mesh's
         # shardings when sharded — specs computed from the CURRENT
         # dstate before overwriting, the key sets are identical)
@@ -2381,7 +2428,9 @@ class Engine:
         and finished requests both included).  The per-request
         ``drafted``/``accepted`` rows sum exactly to the global
         ``spec_drafted``/``spec_accepted`` counters (same attribution
-        invariant as rsw_hits/flex_walks)."""
+        invariant as rsw_hits/flex_walks).  ``queue_wait_s`` is submit ->
+        first prompt chunk admitted and ``admitted_at`` that admission's
+        ``time.perf_counter`` time (both None while still queued)."""
         s = dict(self.manager.stats)
         s["spec_drafted"] = self._spec_drafted
         s["spec_accepted"] = self._spec_accepted
@@ -2432,7 +2481,10 @@ class Engine:
                   "swap_faults": st.swap_faults, "drafted": st.drafted,
                   "accepted": st.accepted,
                   "cached_blocks": st.cached_blocks,
-                  "preempts": st.preempts, "resumes": st.resumes}
+                  "preempts": st.preempts, "resumes": st.resumes,
+                  "queue_wait_s": (None if st.admitted_at is None else
+                                   st.admitted_at - st.submitted_at),
+                  "admitted_at": st.admitted_at}
             for sid, st in self._states.items()}
         if self.partition is not None:
             # per-shard view: each key sums EXACTLY to its global above

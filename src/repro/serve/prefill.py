@@ -111,6 +111,7 @@ def _scatter_pool(pool, cache, slots, mesh: Mesh, spec: DecodeSpec):
 
 # --------------------------------------------------- shared install logic
 
+@jax.named_scope("kv_install")
 def _install_kv(spec, mesh, dstate, new_state, caches, eff_slots, B,
                 part: Optional[Partition] = None):
     """Scatter per-layer chunk K/V (L, B, S, KV, hd) into the pool at
@@ -176,6 +177,7 @@ def _install_recurrent(dstate, new_state, mc, sid, B):
         cv.astype(dstate["conv"].dtype), mode="drop")
 
 
+@jax.named_scope("sample")
 def _first_token_stats(dstate, last, sid, ctx, n_slots, sample):
     """First generated token per row, computed in-graph so the engine can
     fold it into its single per-step device fetch.
@@ -325,6 +327,7 @@ def make_prefix_prefill_step(cfg: ArchConfig, dims: ModelDims,
     bs = spec.block_size
     fam = cfg.family
 
+    @jax.named_scope("prefix_read")
     def attn_read(q, k_new, v_new, kp_l, vp_l, prefix_slots, prefix_ctx):
         B, S, H, hd = q.shape
         KV = k_new.shape[2]
@@ -387,6 +390,7 @@ def make_prefix_prefill_step(cfg: ArchConfig, dims: ModelDims,
         return dense_attention(q, k_full, v_full, causal=True,
                                q_offset=prefix_ctx)
 
+    @jax.named_scope("attn")
     def attn_sublayer(blk, x, kp_l, vp_l, prefix_slots, positions,
                       prefix_ctx):
         B, S, _ = x.shape
@@ -524,10 +528,12 @@ def make_prefix_prefill_step(cfg: ArchConfig, dims: ModelDims,
         new_state["ctx_len"] = dstate["ctx_len"].at[sid].set(
             ctx.astype(dstate["ctx_len"].dtype), mode="drop")
 
-        x = Lmod.rms_norm(x, params["final_norm"].astype(jnp.float32),
-                          cfg.norm_eps)
-        head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-        logits = Lmod.unembed(head, x, dims.logical_vocab, pins)
+        with jax.named_scope("lm_head"):
+            x = Lmod.rms_norm(x, params["final_norm"].astype(jnp.float32),
+                              cfg.norm_eps)
+            head = (params["embed"] if cfg.tie_embeddings
+                    else params["lm_head"])
+            logits = Lmod.unembed(head, x, dims.logical_vocab, pins)
         last = jnp.take_along_axis(
             logits, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         stats = _first_token_stats(dstate, last, sid, ctx, n_slots, sample)

@@ -56,9 +56,9 @@ from repro.models import layers as Lmod
 from repro.models.transformer import ModelDims
 from repro.kernels.paged_attention.ref import (paged_attention_ref,
                                                paged_attention_blocks)
-from .decode import (DecodeSpec, _psum_gather_blocks, decode_cross,
-                     decode_ffn, project_logits, translate_step,
-                     translate_step_sharded)
+from .decode import (DecodeSpec, _pool_layer, _pool_write_back,
+                     _psum_gather_blocks, decode_cross, decode_ffn,
+                     project_logits, translate_step, translate_step_sharded)
 from .sampling import sample_tokens_q, verify_draft_tokens
 
 # families whose decode state is position-indexed only (KV pool / cross
@@ -150,6 +150,7 @@ def make_spec_decode_step(cfg: ArchConfig, dims: ModelDims,
             k = Lmod.apply_rope(k, positions, cfg.rope_theta)
         return q, k, v
 
+    @jax.named_scope("attn")
     def attn_sublayer(blk, x, kp_l, vp_l, slots_b, w_slot, w_valid,
                       positions, ctx_q):
         B = x.shape[0]
@@ -215,22 +216,23 @@ def make_spec_decode_step(cfg: ArchConfig, dims: ModelDims,
         stats = {}
 
         # ---- the step's single translation dispatch ----------------------
-        if sharded:
-            trans = translate_step_sharded(
-                dstate["tar"], dstate["sf"], dstate["flex"], pos0, spec,
-                part)
-        else:
-            trans = translate_step(dstate["tar"], dstate["sf"],
-                                   dstate["flex"], pos0, spec)
+        with jax.named_scope("translate"):
+            if sharded:
+                trans = translate_step_sharded(
+                    dstate["tar"], dstate["sf"], dstate["flex"], pos0, spec,
+                    part)
+            else:
+                trans = translate_step(dstate["tar"], dstate["sf"],
+                                       dstate["flex"], pos0, spec)
+            slots_b = trans.slots[0]                   # (B, nblk); G == 1
+            # per-position write slots GATHERED from the one translation —
+            # position pos+i lives in block (pos+i)//bs, already resolved
+            blk_idx = jnp.clip(positions // bs, 0, nblk - 1)
+            w_slot = jnp.take_along_axis(slots_b, blk_idx, axis=1)
+            w_map = jnp.take_along_axis(trans.mapped[0], blk_idx, axis=1)
+            w_valid = w_map & (positions < nblk * bs) & act[:, None]
         stats.update(slots=trans.slots, in_rest=trans.in_rest,
                      mapped=trans.mapped, accesses=trans.accesses)
-        slots_b = trans.slots[0]                       # (B, nblk); G == 1
-        # per-position write slots GATHERED from the one translation —
-        # position pos+i lives in block (pos+i)//bs, already resolved
-        blk_idx = jnp.clip(positions // bs, 0, nblk - 1)
-        w_slot = jnp.take_along_axis(slots_b, blk_idx, axis=1)
-        w_map = jnp.take_along_axis(trans.mapped[0], blk_idx, axis=1)
-        w_valid = w_map & (positions < nblk * bs) & act[:, None]
 
         xs = {"blk": params["layers"],
               "idx": jnp.arange(n_layers, dtype=jnp.int32)}
@@ -242,12 +244,10 @@ def make_spec_decode_step(cfg: ArchConfig, dims: ModelDims,
             x, kp, vp = carry
             blk = xl["blk"]
             i = xl["idx"]
-            kp_l = jax.lax.dynamic_index_in_dim(kp, i, 0, keepdims=False)
-            vp_l = jax.lax.dynamic_index_in_dim(vp, i, 0, keepdims=False)
+            kp_l, vp_l = _pool_layer(kp, vp, i)
             x, kp_l, vp_l = attn_sublayer(blk, x, kp_l, vp_l, slots_b,
                                           w_slot, w_valid, positions, ctx_q)
-            kp = jax.lax.dynamic_update_index_in_dim(kp, kp_l, i, 0)
-            vp = jax.lax.dynamic_update_index_in_dim(vp, vp_l, i, 0)
+            kp, vp = _pool_write_back(kp, vp, kp_l, vp_l, i)
             if fam == "audio":
                 x = decode_cross(blk, x, xl["ck"], xl["cv"], cfg, dims,
                                  pins)
@@ -263,13 +263,15 @@ def make_spec_decode_step(cfg: ArchConfig, dims: ModelDims,
         # ---- in-graph accept: greedy exact-match / seeded coupled
         # rejection sampling — every target draw folds its ABSOLUTE
         # position, the same key the non-speculative step would fold
-        if sample:
-            tgt = sample_tokens_q(logits, dstate["samp_temp"],
-                                  dstate["samp_topk"], dstate["samp_topp"],
-                                  dstate["samp_key"], positions)
-        else:
-            tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        acc_tokens, n_emit = verify_draft_tokens(tgt, drafts)
+        with jax.named_scope("sample"):
+            if sample:
+                tgt = sample_tokens_q(logits, dstate["samp_temp"],
+                                      dstate["samp_topk"],
+                                      dstate["samp_topp"],
+                                      dstate["samp_key"], positions)
+            else:
+                tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            acc_tokens, n_emit = verify_draft_tokens(tgt, drafts)
 
         # emitted token i sits at position pos+i+1; rejected tails and
         # inactive rows drop (garbage must not enter the match history)

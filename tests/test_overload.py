@@ -521,7 +521,8 @@ def test_overload_stats_block_and_per_request_shape():
     for row in s["per_request"].values():
         assert set(row) == {"rsw_hits", "flex_walks", "swap_faults",
                             "drafted", "accepted", "cached_blocks",
-                            "preempts", "resumes"}
+                            "preempts", "resumes", "queue_wait_s",
+                            "admitted_at"}
     # no ids were reused in this run: the rows carry the whole account
     assert (sum(r["preempts"] for r in s["per_request"].values())
             == ov["request_preempts"])

@@ -268,7 +268,8 @@ def test_stats_attributes_translation_per_request(setup):
     for row in per.values():
         assert set(row) == {"rsw_hits", "flex_walks", "swap_faults",
                             "drafted", "accepted", "cached_blocks",
-                            "preempts", "resumes"}
+                            "preempts", "resumes", "queue_wait_s",
+                            "admitted_at"}
         # spec decode is off: no drafts were ever proposed
         assert row["drafted"] == row["accepted"] == 0
     # decode telemetry is attributed exhaustively: per-request rows sum
