@@ -495,12 +495,15 @@ def _paged_attn_shardmap(q, k_new, v_new, k_pool_l, v_pool_l, slots, w_slot,
 
 # ------------------------------------- slot-sharded pool (SPMD engine)
 
-def _psum_gather_blocks(pool_l, slots, part: Partition, model_axis: str):
+def _psum_gather_blocks(pool, slots, part: Partition, model_axis: str,
+                        layer=None):
     """Gather blocks by LOGICAL slot from the slot-sharded pool, exactly.
 
-    Runs under shard_map over ``model_axis``: ``pool_l`` is this shard's
-    contiguous physical-slot chunk ``(slots_per_shard, bs, KV, hd)``;
-    ``slots`` the replicated logical slot ids (any leading shape, -1 =
+    Runs under shard_map over ``model_axis``: ``pool`` is this shard's
+    contiguous physical-slot chunk ``(slots_per_shard, bs, KV, hd)`` or,
+    with ``layer``, the whole ``(L, slots_per_shard, bs, KV, hd)`` carry,
+    read at that layer in the same gather; ``slots`` the replicated
+    logical slot ids (any leading shape, -1 =
     unmapped).  Each shard gathers the blocks it owns, then an INTEGER
     psum over the raw bits assembles the replicated result — float
     psums would tie bit-identity to reduction order; bit psums of
@@ -512,7 +515,8 @@ def _psum_gather_blocks(pool_l, slots, part: Partition, model_axis: str):
     cps = part.slots_per_shard
     phys = part.phys(slots)
     mine = (slots >= 0) & ((phys // cps) == m)
-    g = pool_l[jnp.where(mine, phys - m * cps, 0)]
+    idx = jnp.where(mine, phys - m * cps, 0)
+    g = pool[idx] if layer is None else pool[layer, idx]
     mask = mine.reshape(mine.shape + (1,) * (g.ndim - mine.ndim))
     if g.dtype == jnp.float32:
         bits = jax.lax.bitcast_convert_type(g, jnp.int32)
@@ -524,33 +528,31 @@ def _psum_gather_blocks(pool_l, slots, part: Partition, model_axis: str):
     return jax.lax.bitcast_convert_type(bits.astype(jnp.uint16), g.dtype)
 
 
-def _paged_attn_shard_local(q, k_new, v_new, kp_l, vp_l,
+def _paged_attn_shard_local(q, k_new, v_new, kp, vp, i,
                             trans: StepTranslation, pos,
                             spec: DecodeSpec, part: Partition):
     """Slot-sharded write + paged attention (runs under shard_map).
 
     The sharded twin of ``_paged_attn_local_ref``: the current token's
-    K/V scatter is ownership-masked (only the shard owning the physical
-    slot writes; everyone else drops out of bounds), the block gather is
-    the exact bit-psum assembly, and the attention math itself is the
-    SAME replicated ``paged_attention_blocks`` — bitwise identical
-    output to the mesh-free reference.
+    K/V scatter into layer ``i`` of this shard's carried pools is
+    ownership-masked (only the shard owning the physical slot writes;
+    everyone else drops out of bounds), the block gather is the exact
+    bit-psum assembly, and the attention math itself is the SAME
+    replicated ``paged_attention_blocks`` — bitwise identical output to
+    the mesh-free reference.
     """
     m = jax.lax.axis_index(spec.model_axis)
     cps = part.slots_per_shard
     slots = trans.slots[0]                          # (B, nblk) logical
     w_slot, w_valid = trans.w_slot[0], trans.w_valid[0]
-    t = pos % spec.block_size
     wp = part.phys(w_slot)
-    mine_w = w_valid & ((wp // cps) == m)
-    ws = jnp.where(mine_w, wp - m * cps, cps)       # unowned -> dropped
-    kp_l = kp_l.at[ws, t].set(k_new.astype(kp_l.dtype), mode="drop")
-    vp_l = vp_l.at[ws, t].set(v_new.astype(vp_l.dtype), mode="drop")
-    k = _psum_gather_blocks(kp_l, slots, part, spec.model_axis)
-    v = _psum_gather_blocks(vp_l, slots, part, spec.model_axis)
+    kp, vp = _pool_write(kp, vp, i, wp - m * cps, pos % spec.block_size,
+                         k_new, v_new, w_valid & ((wp // cps) == m))
+    k = _psum_gather_blocks(kp, slots, part, spec.model_axis, i)
+    v = _psum_gather_blocks(vp, slots, part, spec.model_axis, i)
     o, mx, l = paged_attention_blocks(q, k, v, slots, pos + 1)
     out = (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
-    return out, kp_l, vp_l
+    return out, kp, vp
 
 
 # ---------------------------------------------- shared decode sublayers
@@ -564,18 +566,29 @@ def _paged_attn_shard_local(q, k_new, v_new, kp_l, vp_l,
 # bitwise the pre-refactor one.
 
 @jax.named_scope("kv_pool")
-def _pool_layer(kp, vp, i):
-    """Layer ``i``'s K and V pools, sliced out of the scan-carried
-    (L, slots, ...) pools."""
-    return (jax.lax.dynamic_index_in_dim(kp, i, 0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(vp, i, 0, keepdims=False))
+def _pool_write(kp, vp, i, ws, t, k_new, v_new, valid):
+    """Write new tokens' K/V into layer ``i`` of the scan-carried
+    ``(L, slots, bs, KV, hd)`` pools, in place, at ``[i, ws, t]``.
+
+    The one pool write of every decode scan body (scalar, verify,
+    slot-sharded): ``ws``/``t``/``valid`` share a leading shape — ``(B,)``
+    or the verify window's ``(B, K+1)`` — and ``k_new``/``v_new`` add
+    ``(KV, hd)``.  Rows that are not ``valid`` (unmapped, inactive, not
+    this shard's) scatter to an out-of-bounds slot and are DROPPED —
+    clamping them to slot 0 would collide with a live block and clobber
+    its fresh write.
+    """
+    ws = jnp.where(valid, ws, kp.shape[1])
+    return (kp.at[i, ws, t].set(k_new.astype(kp.dtype), mode="drop"),
+            vp.at[i, ws, t].set(v_new.astype(vp.dtype), mode="drop"))
 
 
-@jax.named_scope("kv_pool")
-def _pool_write_back(kp, vp, kp_l, vp_l, i):
-    """Write layer ``i``'s updated K and V pools back into the carry."""
-    return (jax.lax.dynamic_update_index_in_dim(kp, kp_l, i, 0),
-            jax.lax.dynamic_update_index_in_dim(vp, vp_l, i, 0))
+def _pool_blocks(pool, i, slots):
+    """Layer ``i``'s blocks at ``slots`` (negative entries clamp to slot
+    0 and are masked by paged attention), gathered straight from the
+    carried ``(L, slots, ...)`` pool in ONE gather — ``pool[i][slots]``
+    would slice the whole layer out first."""
+    return pool[i, jnp.maximum(slots, 0)]
 
 
 @jax.named_scope("ffn")
@@ -677,22 +690,30 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
         raise ValueError("spec.kv_shards >= 1 requires a Partition")
 
     @jax.named_scope("attn")
-    def attn_sublayer(blk, x, kp_l, vp_l, trans, positions):
+    def attn_sublayer(blk, x, kp, vp, i, trans, positions):
+        """Attention of layer ``i``; ``kp``/``vp`` are the carried
+        ``(L, ...)`` pools, returned with this layer's write done."""
         B = x.shape[0]
         q, k, v = qkv_decode(blk, x, positions)
         if sharded:
-            out, kp_l, vp_l = _paged_attn_shard_local(
-                q, k, v, kp_l, vp_l, trans, positions, spec, part)
+            out, kp, vp = _paged_attn_shard_local(
+                q, k, v, kp, vp, i, trans, positions, spec, part)
         elif mesh is not None:
+            # the legacy token-split path serves no cell (dryrun compile
+            # cells only) and keeps a local slice of the layer's pools
+            kp_l = jax.lax.dynamic_index_in_dim(kp, i, 0, keepdims=False)
+            vp_l = jax.lax.dynamic_index_in_dim(vp, i, 0, keepdims=False)
             out, kp_l, vp_l = _paged_attn_shardmap(
                 q, k, v, kp_l, vp_l, trans.slots, trans.w_slot,
                 trans.w_valid, positions,
                 spec=spec, mesh=mesh, n_kv=dims.n_kv, head_dim=dims.head_dim)
+            kp = jax.lax.dynamic_update_index_in_dim(kp, kp_l, i, 0)
+            vp = jax.lax.dynamic_update_index_in_dim(vp, vp_l, i, 0)
         else:
-            out, kp_l, vp_l = _paged_attn_local_ref(
-                q, k, v, kp_l, vp_l, trans, positions, spec)
+            out, kp, vp = _paged_attn_local_ref(
+                q, k, v, kp, vp, i, trans, positions, spec)
         o = Lmod.linear(blk["attn"]["o"], out.reshape(B, -1).astype(x.dtype))
-        return x + pins("dec_bd", o), kp_l, vp_l
+        return x + pins("dec_bd", o), kp, vp
 
     def ffn_sublayer(blk, x):
         return decode_ffn(blk, x, cfg, pins)
@@ -742,9 +763,10 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
 
         n_layers = cfg.num_layers
         if fam in ("dense", "moe", "vlm", "audio"):
-            # KV pools ride in the scan CARRY with per-layer in-place
-            # dynamic updates (single live buffer; xs/ys would double-buffer
-            # the multi-TB pool)
+            # KV pools ride in the scan CARRY at their full (L, ...)
+            # shape (single live buffer; xs/ys would double-buffer the
+            # multi-TB pool): each layer scatters its tokens' K/V into
+            # the carry in place and gathers its blocks from it
             xs = {"blk": params["layers"],
                   "idx": jnp.arange(n_layers, dtype=jnp.int32)}
             if fam == "audio":
@@ -755,10 +777,8 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
                 x, kp, vp = carry
                 blk = xl["blk"]
                 i = xl["idx"]
-                kp_l, vp_l = _pool_layer(kp, vp, i)
-                x, kp_l, vp_l = attn_sublayer(blk, x, kp_l, vp_l, trans,
-                                              positions)
-                kp, vp = _pool_write_back(kp, vp, kp_l, vp_l, i)
+                x, kp, vp = attn_sublayer(blk, x, kp, vp, i, trans,
+                                          positions)
                 if fam == "audio":
                     x = cross_sublayer(blk, x, xl["ck"], xl["cv"], None)
                 x = ffn_sublayer(blk, x)
@@ -806,10 +826,8 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
                         ssm_out.append(s)
                         conv_out.append(c)
                     else:
-                        kp_l, vp_l = _pool_layer(kp, vp, gi)
-                        x, kp_l, vp_l = attn_sublayer(
-                            blk["attn"], x, kp_l, vp_l, trans, positions)
-                        kp, vp = _pool_write_back(kp, vp, kp_l, vp_l, gi)
+                        x, kp, vp = attn_sublayer(
+                            blk["attn"], x, kp, vp, gi, trans, positions)
                     x = ffn_sublayer(Tmod.hybrid_ffn_select(cfg, blk, i), x)
                 return (x, kp, vp), {"ssm": jnp.stack(ssm_out),
                                      "conv": jnp.stack(conv_out)}
@@ -877,19 +895,20 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
 
 # ------------------------------------------------ single-device reference
 
-def _paged_attn_local_ref(q, k_new, v_new, kp_l, vp_l,
+def _paged_attn_local_ref(q, k_new, v_new, kp, vp, i,
                           trans: StepTranslation, pos,
                           spec: DecodeSpec):
     """Mesh-free reference used by the engine on one device (G=1, TP=1).
 
     Consumes the pre-resolved ``StepTranslation`` — no translation here.
+    Writes the current token into layer ``i`` of the carried pools and
+    attends over that layer's blocks gathered from them.
     """
     slots = trans.slots[0]                          # (B, nblk)
-    w_slot, w_valid = trans.w_slot[0], trans.w_valid[0]
-    t = pos % spec.block_size
-    ws = jnp.where(w_valid, w_slot, kp_l.shape[0])  # unowned -> dropped
-    kp_l = kp_l.at[ws, t].set(k_new.astype(kp_l.dtype), mode="drop")
-    vp_l = vp_l.at[ws, t].set(v_new.astype(vp_l.dtype), mode="drop")
-    o, m, l = paged_attention_ref(q, kp_l, vp_l, slots, pos + 1)
+    kp, vp = _pool_write(kp, vp, i, trans.w_slot[0], pos % spec.block_size,
+                         k_new, v_new, trans.w_valid[0])
+    o, m, l = paged_attention_blocks(q, _pool_blocks(kp, i, slots),
+                                     _pool_blocks(vp, i, slots),
+                                     slots, pos + 1)
     out = (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
-    return out, kp_l, vp_l
+    return out, kp, vp

@@ -54,9 +54,8 @@ from repro.core.partition import Partition
 from repro.dist.sharding import kv_state_specs
 from repro.models import layers as Lmod
 from repro.models.transformer import ModelDims
-from repro.kernels.paged_attention.ref import (paged_attention_ref,
-                                               paged_attention_blocks)
-from .decode import (DecodeSpec, _pool_layer, _pool_write_back,
+from repro.kernels.paged_attention.ref import paged_attention_blocks
+from .decode import (DecodeSpec, _pool_blocks, _pool_write,
                      _psum_gather_blocks, decode_cross, decode_ffn,
                      project_logits, translate_step, translate_step_sharded)
 from .sampling import sample_tokens_q, verify_draft_tokens
@@ -151,13 +150,13 @@ def make_spec_decode_step(cfg: ArchConfig, dims: ModelDims,
         return q, k, v
 
     @jax.named_scope("attn")
-    def attn_sublayer(blk, x, kp_l, vp_l, slots_b, w_slot, w_valid,
+    def attn_sublayer(blk, x, kp, vp, i, slots_b, w_slot, w_valid,
                       positions, ctx_q):
         B = x.shape[0]
         q, k, v = qkv_verify(blk, x, positions)
-        # write ALL window positions' K/V into their pre-resolved slots;
-        # invalid (unmapped / inactive / out-of-range) scatter out of
-        # bounds and drop — clamping would clobber a live block
+        # write ALL window positions' K/V into their pre-resolved slots of
+        # layer i of the carried pools; invalid (unmapped / inactive /
+        # out-of-range) positions drop
         t_loc = positions % bs
         if sharded:
             # ownership-masked write + exact bit-psum gather; the Q>1
@@ -165,28 +164,20 @@ def make_spec_decode_step(cfg: ArchConfig, dims: ModelDims,
             m_idx = jax.lax.axis_index(spec.model_axis)
             cps = part.slots_per_shard
             wp = part.phys(w_slot)
-            mine_w = w_valid & ((wp // cps) == m_idx)
-            ws = jnp.where(mine_w, wp - m_idx * cps, kp_l.shape[0])
-            kp_l = kp_l.at[ws, t_loc].set(k.astype(kp_l.dtype),
-                                          mode="drop")
-            vp_l = vp_l.at[ws, t_loc].set(v.astype(vp_l.dtype),
-                                          mode="drop")
-            gk = _psum_gather_blocks(kp_l, slots_b, part, spec.model_axis)
-            gv = _psum_gather_blocks(vp_l, slots_b, part, spec.model_axis)
-            o, m_, l_ = paged_attention_blocks(q, gk, gv, slots_b, ctx_q)
+            kp, vp = _pool_write(kp, vp, i, wp - m_idx * cps, t_loc, k, v,
+                                 w_valid & ((wp // cps) == m_idx))
+            gk = _psum_gather_blocks(kp, slots_b, part, spec.model_axis, i)
+            gv = _psum_gather_blocks(vp, slots_b, part, spec.model_axis, i)
         else:
-            ws = jnp.where(w_valid, w_slot, kp_l.shape[0])
-            kp_l = kp_l.at[ws, t_loc].set(k.astype(kp_l.dtype),
-                                          mode="drop")
-            vp_l = vp_l.at[ws, t_loc].set(v.astype(vp_l.dtype),
-                                          mode="drop")
-            # per-query extents pos+i+1: the sequential causal mask,
-            # inside one pool read (verify-shaped Q>1 paged attention)
-            o, m_, l_ = paged_attention_ref(q, kp_l, vp_l, slots_b, ctx_q)
+            kp, vp = _pool_write(kp, vp, i, w_slot, t_loc, k, v, w_valid)
+            gk, gv = _pool_blocks(kp, i, slots_b), _pool_blocks(vp, i, slots_b)
+        # per-query extents pos+i+1: the sequential causal mask, inside
+        # one pool read (verify-shaped Q>1 paged attention)
+        o, m_, l_ = paged_attention_blocks(q, gk, gv, slots_b, ctx_q)
         out = (o / jnp.maximum(l_, 1e-30)[..., None]).astype(q.dtype)
         o_p = Lmod.linear(blk["attn"]["o"],
                           out.reshape(B, Qw, -1).astype(x.dtype))
-        return x + pins("dec_bd", o_p), kp_l, vp_l
+        return x + pins("dec_bd", o_p), kp, vp
 
     n_layers = cfg.num_layers
 
@@ -244,10 +235,8 @@ def make_spec_decode_step(cfg: ArchConfig, dims: ModelDims,
             x, kp, vp = carry
             blk = xl["blk"]
             i = xl["idx"]
-            kp_l, vp_l = _pool_layer(kp, vp, i)
-            x, kp_l, vp_l = attn_sublayer(blk, x, kp_l, vp_l, slots_b,
-                                          w_slot, w_valid, positions, ctx_q)
-            kp, vp = _pool_write_back(kp, vp, kp_l, vp_l, i)
+            x, kp, vp = attn_sublayer(blk, x, kp, vp, i, slots_b, w_slot,
+                                      w_valid, positions, ctx_q)
             if fam == "audio":
                 x = decode_cross(blk, x, xl["ck"], xl["cv"], cfg, dims,
                                  pins)

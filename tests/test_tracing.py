@@ -136,8 +136,8 @@ def test_lowered_serve_step_names_every_scope(setup):
         assert re.search(rf'loc\("[^"]*\b{scope}[/"]', text), scope
         assert any(n.startswith("jit(serve_step)/") and f"/{scope}/" in n
                    for n in names), scope
-    # the pool slices are their own scope, inside the layer loop
-    assert any(re.match(r"jit\(serve_step\)/while/body/.*kv_pool/dynamic.sl",
+    # the in-place pool write is its own scope, inside the layer loop
+    assert any(re.match(r"jit\(serve_step\)/while/body/.*kv_pool/scatter",
                         n) for n in names)
 
 
