@@ -12,10 +12,11 @@ from .qwen2_72b import CONFIG as _qwen2_72b
 from .granite_8b import CONFIG as _granite8b
 from .jamba_1_5_large_398b import CONFIG as _jamba
 from .mamba2_130m import CONFIG as _mamba2
+from .mellum2_12b_a2_5b import CONFIG as _mellum2
 
 ARCHS = {c.name: c for c in (
     _qwen3_moe, _llama4_scout, _paligemma, _whisper, _granite3,
-    _qwen25_14b, _qwen2_72b, _granite8b, _jamba, _mamba2,
+    _qwen25_14b, _qwen2_72b, _granite8b, _jamba, _mamba2, _mellum2,
 )}
 
 

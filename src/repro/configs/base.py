@@ -15,6 +15,24 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+# the window of a layer that attends every earlier position
+NO_WINDOW = 1 << 30
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """One rotary table: base ``theta``; ``yarn_factor`` > 0 stretches it
+    by YaRN (arXiv:2309.00071) over ``yarn_original_max_pos`` positions,
+    ramping between the ``yarn_beta_fast`` and ``yarn_beta_slow`` rotation
+    counts; cos and sin are multiplied by ``attention_factor``."""
+    theta: float
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
@@ -36,6 +54,13 @@ class ArchConfig:
     moe_every: int = 1           # MoE on layers where (layer % moe_every == moe_offset)
     moe_offset: int = 0
     moe_capacity_factor: float = 1.25
+    moe_d_ff: int = 0            # expert width where it differs from d_ff
+    # --- attention kinds (sliding-window and full layers mixed) ---
+    # one period of layer kinds, "sliding" | "full", repeated over the
+    # depth; () = every layer full, one rotary table (``rope_theta``)
+    layer_types: Tuple[str, ...] = ()
+    sliding_window: int = 0      # keys a sliding query sees, itself included
+    rope_by_type: Tuple[Tuple[str, Rope], ...] = ()   # default: rope_theta
     # --- hybrid / SSM (mamba2) ---
     attn_every: int = 0          # jamba: one attn layer per this many (0 = all attn)
     ssm_state: int = 0
@@ -61,6 +86,16 @@ class ArchConfig:
     def __post_init__(self):
         if self.family not in ("dense", "moe", "vlm", "audio", "hybrid", "ssm"):
             raise ValueError(f"unknown family {self.family}")
+        if self.mixed_attention:
+            if self.family not in ("dense", "moe"):
+                raise ValueError("layer kinds are only built for the "
+                                 f"dense and moe families, not {self.family}")
+            kinds = set(self.layer_types) | {t for t, _ in self.rope_by_type}
+            if not kinds <= {"sliding", "full"}:
+                raise ValueError(f"unknown layer kinds {sorted(kinds)}")
+            if ("sliding" in self.layer_types) != (self.sliding_window > 0):
+                raise ValueError("sliding layers need a sliding_window > 0 "
+                                 "and a window needs sliding layers")
 
     @property
     def resolved_head_dim(self) -> int:
@@ -71,6 +106,30 @@ class ArchConfig:
     @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def mixed_attention(self) -> bool:
+        """Layers differ in window or rotary table: the layer scans then
+        take each layer's window and table as scanned inputs."""
+        return bool(self.layer_types or self.rope_by_type)
+
+    def layer_type(self, layer: int) -> str:
+        if not self.layer_types:
+            return "full"
+        return self.layer_types[layer % len(self.layer_types)]
+
+    def layer_window(self, layer: int) -> int:
+        """Keys a query of ``layer`` attends, itself included."""
+        if self.layer_type(layer) == "sliding":
+            return self.sliding_window
+        return NO_WINDOW
+
+    def rope_of(self, layer_type: str) -> Rope:
+        return dict(self.rope_by_type).get(layer_type, Rope(self.rope_theta))
 
     def moe_on_layer(self, layer: int) -> bool:
         if self.moe_num_experts == 0:
@@ -93,7 +152,7 @@ class ArchConfig:
         if self.qkv_bias:
             attn += hd * (nq + 2 * nkv)
         dense_mlp = 3 * d * self.d_ff                         # swiglu
-        moe_mlp = self.moe_num_experts * 3 * d * self.d_ff \
+        moe_mlp = self.moe_num_experts * 3 * d * self.expert_d_ff \
             + d * self.moe_num_experts                        # experts + router
         d_inner = self.ssm_expand * d
         nheads_ssm = max(1, d_inner // self.ssm_head_dim)
@@ -128,7 +187,8 @@ class ArchConfig:
         d = self.d_model
         inactive_experts = self.moe_num_experts - self.moe_top_k
         n_moe_layers = sum(self.moe_on_layer(l) for l in range(self.num_layers))
-        return self.param_count() - n_moe_layers * inactive_experts * 3 * d * self.d_ff
+        return (self.param_count()
+                - n_moe_layers * inactive_experts * 3 * d * self.expert_d_ff)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -209,6 +269,8 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         num_kv_heads=min(max(1, cfg.num_kv_heads // max(1, cfg.num_heads // 4)), 4),
         head_dim=16,
         d_ff=128 if cfg.d_ff else 0,
+        moe_d_ff=32 if cfg.moe_d_ff else 0,
+        sliding_window=min(cfg.sliding_window, 16),
         vocab_size=256,
         moe_num_experts=min(cfg.moe_num_experts, 8),
         moe_top_k=min(cfg.moe_top_k, 2),

@@ -175,6 +175,6 @@ def make_megatron_forward(cfg: ArchConfig, dims: ModelDims, mesh: Mesh,
             in_specs=(param_specs(params), P(DA, None)),
             out_specs=P(DA, None, None), check_vma=False)
         logits = fn(params, tokens)
-        return logits, _zero_aux(), None
+        return logits, _zero_aux(cfg, moe_capacity=True), None
 
     return fwd

@@ -56,7 +56,7 @@ def paged_attention_ref(q, k_pool, v_pool, slots, ctx_len, *,
 
 def paged_attention_blocks(q, k, v, slots, ctx_len, *,
                            tok_offset: int = 0, tok_stride: int = 1,
-                           block_tokens: int | None = None):
+                           block_tokens: int | None = None, window=None):
     """``paged_attention_ref`` with the gather already done.
 
     ``k``/``v`` are the PRE-GATHERED per-row blocks ``(B, nblk, bs, KV, D)``
@@ -65,6 +65,10 @@ def paged_attention_blocks(q, k, v, slots, ctx_len, *,
     instead of the clamp-gathered slot-0 data; both are bitwise-safe, the
     mask below NEG_INFs those scores before they contribute).  ``slots``
     is still taken for the validity mask.
+
+    ``window`` (a scalar, may be traced) is a sliding-window layer's band
+    for queries that sit at position ``ctx_len - 1`` (decode, verify):
+    positions below ``ctx_len - window`` are masked too.
     """
     squeeze = q.ndim == 3
     if squeeze:
@@ -87,6 +91,8 @@ def paged_attention_blocks(q, k, v, slots, ctx_len, *,
         ctx_q = ctx_len                                 # (B, Q)
     valid = ((pos[None, None] < ctx_q[:, :, None, None])
              & (slots >= 0)[:, None, :, None])          # (B, Qc, nblk, bs)
+    if window is not None:
+        valid &= pos[None, None] >= (ctx_q - window)[:, :, None, None]
     vflat = valid.reshape(B, 1, 1, valid.shape[1], -1)  # (B,1,1,Qc,nblk*bs)
 
     qk = q.astype(jnp.float32).reshape(B, Q, KV, g, D)

@@ -30,7 +30,8 @@ def _gqa_fold(q: jax.Array, n_kv: int):
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
                     q_offset=0,
-                    kv_len: Optional[jax.Array] = None) -> jax.Array:
+                    kv_len: Optional[jax.Array] = None,
+                    window=None) -> jax.Array:
     """q: (B,Sq,H,hd), k/v: (B,Skv,Kv,hd) -> (B,Sq,H,hd).
 
     ``q_offset`` positions the queries inside the causal mask: a scalar
@@ -41,6 +42,9 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     a chunk query attending over [gathered prefix + own chunk] K/V laid
     out at their absolute positions reproduces the full-sequence forward
     bit for bit.
+
+    ``window`` (causal only; a scalar, may be traced) keeps the keys with
+    ``q - k < window``: a sliding-window layer's band.
     """
     B, Sq, H, D = q.shape
     Skv, Kv = k.shape[1], k.shape[2]
@@ -54,10 +58,14 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         if off.ndim == 0:
             qpos = jnp.arange(Sq) + off
             mask = qpos[:, None] >= cpos[None, :]
+            if window is not None:
+                mask &= (qpos[:, None] - cpos[None, :]) < window
             s = jnp.where(mask, s, NEG_INF)
         else:                                  # per-row offsets (B,)
             qpos = off[:, None] + jnp.arange(Sq)[None, :]
             mask = qpos[:, :, None] >= cpos[None, None, :]   # (B, Sq, Skv)
+            if window is not None:
+                mask &= (qpos[:, :, None] - cpos[None, None, :]) < window
             s = jnp.where(mask[:, None, None], s, NEG_INF)
     if kv_len is not None:
         valid = jnp.arange(Skv)[None, :] < kv_len[:, None]     # (B, Skv)
@@ -193,10 +201,13 @@ def flash_attention_jax(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def attention(q, k, v, *, impl: str = "dense", causal: bool = True,
               q_offset: int = 0, q_chunk: int = 512, kv_chunk: int = 512,
               triangular_schedule: bool = False,
-              kv_len: Optional[jax.Array] = None) -> jax.Array:
+              kv_len: Optional[jax.Array] = None, window=None) -> jax.Array:
     if impl == "dense":
         return dense_attention(q, k, v, causal=causal, q_offset=q_offset,
-                               kv_len=kv_len)
+                               kv_len=kv_len, window=window)
+    if window is not None:
+        raise NotImplementedError(f"attention impl {impl!r} has no "
+                                  "sliding-window mask; use 'dense'")
     if impl == "flash_jax":
         if kv_len is not None:
             raise NotImplementedError("flash_jax is for train/prefill "

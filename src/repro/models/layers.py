@@ -13,10 +13,11 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Callable, Optional
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Pins = Callable[[str, jax.Array], jax.Array]
 
@@ -77,13 +78,62 @@ def rope_frequencies(head_dim: int, theta: float, dtype=jnp.float32):
     return (1.0 / (theta ** exponents)).astype(dtype)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq)."""
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original_max_pos: int, beta_fast: float,
+                     beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies (arXiv:2309.00071), as transformers'
+    ``_compute_yarn_parameters`` defines them (``truncate`` on): each
+    frequency blends the original (extrapolated) and the ``factor``-times
+    slower (interpolated) one, by a linear ramp over the dimensions whose
+    rotation count over ``original_max_pos`` positions lies between
+    ``beta_fast`` and ``beta_slow``.  float32, shape (head_dim / 2,)."""
+    def corr_dim(rotations):
+        return (head_dim * math.log(original_max_pos
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                          / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    extrapolate = 1.0 - ramp
+    inv = (1.0 / (factor * pos_freqs)) * (1.0 - extrapolate) \
+        + (1.0 / pos_freqs) * extrapolate
+    return inv.astype(np.float32)
+
+
+def rope_table(head_dim: int, rope) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies (head_dim / 2,) float32, cos/sin scale) of a
+    ``configs.base.Rope``."""
+    if rope.yarn_factor > 0:
+        inv = yarn_frequencies(head_dim, rope.theta, rope.yarn_factor,
+                               rope.yarn_original_max_pos,
+                               rope.yarn_beta_fast, rope.yarn_beta_slow)
+    else:
+        inv = 1.0 / (rope.theta ** (np.arange(0, head_dim, 2,
+                                              dtype=np.float32) / head_dim))
+    return inv.astype(np.float32), float(rope.attention_factor)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               inv_freq: Optional[jax.Array] = None,
+               scale: Optional[jax.Array] = None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
+
+    ``inv_freq`` (head_dim / 2,) and ``scale`` (cos/sin factor) replace
+    the default table at ``theta`` (a layer's own table, ``rope_table``).
+    """
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)                       # (hd/2,)
+    freqs = rope_frequencies(hd, theta) if inv_freq is None else inv_freq
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., None, :]                       # (..., seq, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -143,16 +193,17 @@ def init_attention(key, d_model: int, n_heads: int, n_kv: int, head_dim: int,
 
 def qkv_project(p: dict, x: jax.Array, xkv: jax.Array, n_heads: int,
                 n_kv: int, head_dim: int, positions, kv_positions,
-                rope_theta: float, pins: Pins = no_pins):
-    """Returns q (B,S,H,hd), k/v (B,Skv,Kv,hd) with RoPE applied."""
+                rope_theta: float, pins: Pins = no_pins, rope=()):
+    """Returns q (B,S,H,hd), k/v (B,Skv,Kv,hd) with RoPE applied;
+    ``rope`` = (inv_freq, scale) is the layer's own table."""
     B, S, _ = x.shape
     Skv = xkv.shape[1]
     q = linear(p["q"], x).reshape(B, S, n_heads, head_dim)
     k = linear(p["k"], xkv).reshape(B, Skv, n_kv, head_dim)
     v = linear(p["v"], xkv).reshape(B, Skv, n_kv, head_dim)
-    if rope_theta > 0:
-        q = apply_rope(q, positions, rope_theta)
-        k = apply_rope(k, kv_positions, rope_theta)
+    if rope or rope_theta > 0:
+        q = apply_rope(q, positions, rope_theta, *rope)
+        k = apply_rope(k, kv_positions, rope_theta, *rope)
     q = pins("act_q", q)
     k = pins("act_kv", k)
     v = pins("act_kv", v)

@@ -15,7 +15,7 @@ Aux losses: Switch-style load balancing + router z-loss.
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -126,18 +126,113 @@ def moe_layer(p: dict, x: jax.Array, *, top_k: int,
     return out.reshape(B, S, D).astype(x.dtype), aux
 
 
-def moe_decode(p: dict, x: jax.Array, *, top_k: int,
-               pins: Pins = no_pins) -> jax.Array:
-    """Decode-time MoE for small token counts: every (sharded) expert
-    computes all B tokens; gates mask the result (B << E*C, no capacity)."""
-    B, D = x.shape
-    E = p["gate"].shape[0]
-    logits = x.astype(jnp.float32) @ p["router"]["w"]
+def _route(p: dict, x: jax.Array, top_k: int):
+    """Softmax over the router's logits in float32, the top ``top_k``
+    experts, gates renormalised to sum to 1: (gates (T, k) f32,
+    experts (T, k) int32) for x (T, D)."""
+    logits = x.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, expert_idx = jax.lax.top_k(probs, top_k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-    w = jnp.einsum("bke,bk->be", jax.nn.one_hot(expert_idx, E), gate_vals)
-    h = jax.nn.silu(jnp.einsum("bd,edf->ebf", x, p["gate"].astype(x.dtype)))
-    h = h * jnp.einsum("bd,edf->ebf", x, p["up"].astype(x.dtype))
-    out_e = jnp.einsum("ebf,efd->ebd", h, p["down"].astype(x.dtype))
-    return jnp.einsum("be,ebd->bd", w.astype(x.dtype), out_e)
+    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True),
+                                        1e-9)
+    return gate_vals, expert_idx
+
+
+def _counts(expert_idx: jax.Array, valid: jax.Array, n_experts: int):
+    """[routed pairs, distinct experts hit] (int32) of the valid tokens."""
+    hit = jnp.zeros((n_experts,), jnp.int32).at[expert_idx].max(
+        jnp.broadcast_to(valid[:, None], expert_idx.shape).astype(jnp.int32))
+    pairs = valid.astype(jnp.int32).sum() * expert_idx.shape[-1]
+    return jnp.stack([pairs, hit.sum()])
+
+
+def _expert_matmul(xs: jax.Array, w: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """xs (R, K) rows sorted by expert, ``group_sizes`` (E,) rows each
+    (rows past their sum belong to none); w (E, K, N) -> (R, N).
+
+    On the TPU one grouped matmul (``lax.ragged_dot``, XLA's native
+    ragged dot).  Elsewhere — the CPU tests — each row multiplies its
+    own expert's matrix: ``ragged_dot``'s fallback there is a dense
+    product over every expert whose rounding follows the row count, and
+    a row's result must not depend on which other rows share the call
+    (the prefix-KV prefill is bit-identical to the recompute one)."""
+    def rowwise(xs, w, group_sizes):
+        ends = jnp.cumsum(group_sizes)
+        e = jnp.searchsorted(ends, jnp.arange(xs.shape[0]), side="right")
+        w_row = jnp.take(w, jnp.minimum(e, w.shape[0] - 1), axis=0)
+        return jnp.einsum("rk,rkn->rn", xs, w_row)
+
+    return jax.lax.platform_dependent(
+        xs, w, group_sizes, tpu=jax.lax.ragged_dot, default=rowwise)
+
+
+def moe_dropless(p: dict, x: jax.Array, *, top_k: int,
+                 valid: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Drop-free routed FFN for serving prefill: x (..., D) -> (out,
+    counts [routed pairs, experts hit]).
+
+    Every (token, expert) pair is computed, whatever the load: the pairs
+    are sorted by expert and each expert's rows go through its matrices
+    in one grouped matmul (``_expert_matmul``), so the expert FLOPs are
+    those of the routed pairs, not of every expert for every token.
+    Tokens where ``valid`` (same leading shape) is False — bucket padding
+    — are routed to no expert and return 0.
+    """
+    lead, D = x.shape[:-1], x.shape[-1]
+    E = p["gate"].shape[0]
+    xt = x.reshape(-1, D)
+    T = xt.shape[0]
+    ok = (jnp.ones((T,), bool) if valid is None
+          else valid.reshape(T).astype(bool))
+    with jax.named_scope("moe_route"):
+        gate_vals, expert_idx = _route(p, xt, top_k)
+        pair_ok = jnp.repeat(ok, top_k)
+        # padding pairs sort behind every expert's group (id E)
+        flat_e = jnp.where(pair_ok, expert_idx.reshape(-1), E)
+        order = jnp.argsort(flat_e, stable=True)
+        group_sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(
+            1, mode="drop")
+        xs = jnp.take(xt, order // top_k, axis=0)           # (T*k, D)
+        counts = _counts(expert_idx, ok, E)
+    with jax.named_scope("moe_experts"):
+        dt = x.dtype
+        h = jax.nn.silu(_expert_matmul(xs, p["gate"].astype(dt),
+                                       group_sizes))
+        h = h * _expert_matmul(xs, p["up"].astype(dt), group_sizes)
+        ys = _expert_matmul(h, p["down"].astype(dt), group_sizes)
+    with jax.named_scope("moe_combine"):
+        # rows past the last group are no expert's: zero, never garbage
+        ys = jnp.where(pair_ok[order][:, None], ys, 0)
+        y = jnp.zeros_like(ys).at[order].set(ys).reshape(T, top_k, D)
+        w = jnp.where(ok[:, None], gate_vals, 0.0).astype(dt)
+        out = jnp.einsum("tkd,tk->td", y, w)
+    return out.reshape(*lead, D), counts
+
+
+def moe_decode(p: dict, x: jax.Array, *, top_k: int,
+               valid: Optional[jax.Array] = None,
+               pins: Pins = no_pins) -> Tuple[jax.Array, jax.Array]:
+    """Decode-time MoE for small token counts: every (sharded) expert
+    computes all B tokens; gates mask the result (no capacity, no drop).
+    At a few dozen tokens a step reads every expert's weights anyway, so
+    the extra FLOPs cost no time while the step is bound by those bytes.
+    Returns (out (B, D), counts [routed pairs, experts hit] of the
+    ``valid`` rows)."""
+    B, D = x.shape
+    E = p["gate"].shape[0]
+    ok = jnp.ones((B,), bool) if valid is None else valid.astype(bool)
+    with jax.named_scope("moe_route"):
+        gate_vals, expert_idx = _route(p, x, top_k)
+        w = jnp.einsum("bke,bk->be", jax.nn.one_hot(expert_idx, E),
+                       gate_vals)
+        counts = _counts(expert_idx, ok, E)
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(jnp.einsum("bd,edf->ebf", x,
+                                   p["gate"].astype(x.dtype)))
+        h = h * jnp.einsum("bd,edf->ebf", x, p["up"].astype(x.dtype))
+        out_e = jnp.einsum("ebf,efd->ebd", h, p["down"].astype(x.dtype))
+    with jax.named_scope("moe_combine"):
+        out = jnp.einsum("be,ebd->bd", w.astype(x.dtype), out_e)
+    return out, counts

@@ -20,11 +20,12 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig, resolve
 from . import layers as L
 from .attention import attention
-from .moe import init_moe, moe_layer
+from .moe import init_moe, moe_dropless, moe_layer
 from .ssm import MambaDims, mamba_dims, init_mamba, mamba_forward
 
 
@@ -37,6 +38,7 @@ class ModelDims(NamedTuple):
     d_ff: int
     mamba: Optional[MambaDims]
     tp: int
+    moe_d_ff: int         # expert width (padded like d_ff)
 
 
 def model_dims(cfg: ArchConfig, tp: int = 1) -> ModelDims:
@@ -45,10 +47,28 @@ def model_dims(cfg: ArchConfig, tp: int = 1) -> ModelDims:
     if cfg.family in ("hybrid", "ssm"):
         md = mamba_dims(cfg.d_model, cfg.ssm_state, cfg.ssm_head_dim,
                         cfg.ssm_expand, cfg.ssm_conv_width, tp=tp)
+    moe_ff = -(-cfg.moe_d_ff // tp) * tp if cfg.moe_d_ff else r.d_ff
     return ModelDims(n_heads=r.num_heads, n_kv=r.num_kv_heads,
                      head_dim=cfg.resolved_head_dim, vocab=r.vocab_size,
                      logical_vocab=cfg.vocab_size, d_ff=r.d_ff, mamba=md,
-                     tp=tp)
+                     tp=tp, moe_d_ff=moe_ff)
+
+
+def layer_kinds(cfg: ArchConfig, head_dim: int) -> Dict[str, jax.Array]:
+    """Per-layer attention kind, stacked over the depth for the layer
+    scans (``cfg.mixed_attention`` only): ``window`` (L,) int32 keys a
+    query attends (``NO_WINDOW`` on full layers), ``inv_freq`` (L, hd/2)
+    and ``rope_scale`` (L,) the layer's rotary table."""
+    tables = {t: L.rope_table(head_dim, cfg.rope_of(t))
+              for t in ("sliding", "full")}
+    types = [cfg.layer_type(l) for l in range(cfg.num_layers)]
+    return {
+        "window": jnp.asarray([cfg.layer_window(l)
+                               for l in range(cfg.num_layers)], jnp.int32),
+        "inv_freq": jnp.asarray(np.stack([tables[t][0] for t in types])),
+        "rope_scale": jnp.asarray([tables[t][1] for t in types],
+                                  jnp.float32),
+    }
 
 
 # --------------------------------------------------------------------- init
@@ -65,7 +85,7 @@ def _init_attn_block(key, cfg: ArchConfig, dims: ModelDims, dtype):
 def _init_ffn(key, cfg: ArchConfig, dims: ModelDims, dtype, use_moe: bool):
     if use_moe:
         return {"norm2": L.init_norm(cfg.d_model, dtype),
-                "moe": init_moe(key, cfg.d_model, dims.d_ff,
+                "moe": init_moe(key, cfg.d_model, dims.moe_d_ff,
                                 cfg.moe_num_experts, dtype)}
     return {"norm2": L.init_norm(cfg.d_model, dtype),
             "mlp": L.init_mlp(key, cfg.d_model, dims.d_ff, dtype)}
@@ -175,7 +195,9 @@ class FwdOptions:
 
 
 @jax.named_scope("attn")
-def _self_attn(blk, x, cfg, dims, opt, pins, causal=True):
+def _self_attn(blk, x, cfg, dims, opt, pins, causal=True, kind=None):
+    """``kind`` (a layer's slice of ``layer_kinds``) gives the layer its
+    own rotary table and window."""
     B, S, _ = x.shape
     pos = jnp.arange(S)[None, :]
     h = L.rms_norm(x, blk["norm1"].astype(jnp.float32), cfg.norm_eps)
@@ -184,24 +206,34 @@ def _self_attn(blk, x, cfg, dims, opt, pins, causal=True):
     # the bytes — measured on granite-8b, EXPERIMENTS.md §Perf)
     h = pins("act_full", h)
     theta = cfg.rope_theta if causal else 0.0   # encoder: no rope (stub pos)
+    rope = () if kind is None else (kind["inv_freq"], kind["rope_scale"])
     q, k, v = L.qkv_project(blk["attn"], h, h, dims.n_heads, dims.n_kv,
-                            dims.head_dim, pos, pos, theta, pins)
+                            dims.head_dim, pos, pos, theta, pins, rope=rope)
     o = attention(q, k, v, impl=opt.attn_impl, causal=causal,
                   q_chunk=opt.q_chunk, kv_chunk=opt.kv_chunk,
-                  triangular_schedule=opt.triangular_schedule)
+                  triangular_schedule=opt.triangular_schedule,
+                  window=None if kind is None else kind["window"])
     o = L.linear(blk["attn"]["o"], o.reshape(B, S, -1))
     return x + pins("act_btd", o), (k, v)
 
 
 @jax.named_scope("ffn")
-def _ffn(blk, x, cfg, dims, opt, pins):
+def _ffn(blk, x, cfg, dims, opt, pins, moe_capacity=False, valid=None):
+    """FFN sublayer -> (x, aux).  MoE: training's capacity-bounded
+    dispatch (``moe_capacity``; aux = its losses) or serving's drop-free
+    one (aux = ``{"moe_counts": [routed pairs, experts hit]}`` of the
+    tokens where ``valid``)."""
     h = L.rms_norm(x, blk["norm2"].astype(jnp.float32), cfg.norm_eps)
     h = pins("act_full", h)
-    if "moe" in blk:
+    if "moe" in blk and moe_capacity:
         out, aux = moe_layer(blk["moe"], h, top_k=cfg.moe_top_k,
                              capacity_factor=cfg.moe_capacity_factor,
                              n_groups=opt.moe_groups, pins=pins)
         return x + pins("act_btd", out), aux
+    if "moe" in blk:
+        out, counts = moe_dropless(blk["moe"], h, top_k=cfg.moe_top_k,
+                                   valid=valid)
+        return x + pins("act_btd", out), {"moe_counts": counts}
     out = L.mlp(blk["mlp"], h, pins)
     return x + pins("act_btd", out), None
 
@@ -218,7 +250,9 @@ def hybrid_ffn_select(cfg: ArchConfig, blk, i: int):
     return jax.tree.map(lambda a, j=i - n_moe_before: a[j], blk["mlp"])
 
 
-def _zero_aux():
+def _zero_aux(cfg: ArchConfig, moe_capacity: bool):
+    if cfg.moe_num_experts and not moe_capacity:
+        return {"moe_counts": jnp.zeros((2,), jnp.int32)}
     return {"lb_loss": jnp.zeros((), jnp.float32),
             "z_loss": jnp.zeros((), jnp.float32),
             "fraction_dropped": jnp.zeros((), jnp.float32)}
@@ -241,21 +275,31 @@ def _mamba_block(blk, x, cfg, dims, opt, pins, collect=False,
 
 
 def _decoder_body(cfg: ArchConfig, dims: ModelDims, opt: FwdOptions, pins,
-                  seq_len=None):
-    """Returns the scan body for the family's stacked layers.
+                  seq_len=None, moe_capacity=False):
+    """Returns the scan body for the family's stacked layers; with
+    ``cfg.mixed_attention`` each step also takes its layer's kind.
 
     ``seq_len`` (B,) is forwarded to the recurrent (mamba) sublayers so
     right-padded bucket rows install exact SSM states (pad positions are
-    identity transitions); attention sublayers need no mask — causal
-    attention never reads past the query position."""
+    identity transitions), and keeps the pad positions out of the
+    drop-free MoE; attention sublayers need no mask — causal attention
+    never reads past the query position."""
     fam = cfg.family
+    mixed = cfg.mixed_attention
 
-    def body(carry, blk):
+    def ffn(blk, x):
+        valid = None
+        if seq_len is not None and cfg.moe_num_experts:
+            valid = jnp.arange(x.shape[1])[None, :] < seq_len[:, None]
+        return _ffn(blk, x, cfg, dims, opt, pins, moe_capacity, valid)
+
+    def body(carry, xl):
+        blk, kind = xl if mixed else (xl, None)
         x, aux = carry
         cache = {}
         if fam in ("dense", "moe", "vlm"):
-            x, (k, v) = _self_attn(blk, x, cfg, dims, opt, pins)
-            x, a = _ffn(blk, x, cfg, dims, opt, pins)
+            x, (k, v) = _self_attn(blk, x, cfg, dims, opt, pins, kind=kind)
+            x, a = ffn(blk, x)
             aux = _acc_aux(aux, a)
             if opt.collect_cache:
                 cache = {"k": k, "v": v}
@@ -279,8 +323,7 @@ def _decoder_body(cfg: ArchConfig, dims: ModelDims, opt: FwdOptions, pins,
                     k = v = None
                 else:
                     x, (k, v) = _self_attn(blk["attn"], x, cfg, dims, opt, pins)
-                x, a = _ffn(hybrid_ffn_select(cfg, blk, i), x, cfg, dims,
-                            opt, pins)
+                x, a = ffn(hybrid_ffn_select(cfg, blk, i), x)
                 aux = _acc_aux(aux, a)
             if opt.collect_cache:
                 stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *ssm_states)
@@ -333,11 +376,14 @@ def _audio_decoder_body(cfg, dims, opt, pins, enc_out):
 
 def forward(params, batch: Dict[str, jax.Array], cfg: ArchConfig,
             dims: ModelDims, opt: FwdOptions = FwdOptions(),
-            pins: L.Pins = L.no_pins):
-    """batch: tokens (B,S) [+ frontend (B,F,D) for vlm/audio].
+            pins: L.Pins = L.no_pins, *, moe_capacity: bool = False):
+    """batch: tokens (B,S) [+ frontend (B,F,D) for vlm/audio] [+ seq_len
+    (B,) real row lengths].
 
     Returns (logits (B,S,vocab_pad), aux, caches) — caches None unless
-    ``opt.collect_cache``.
+    ``opt.collect_cache``.  MoE layers route drop-free (serving; aux
+    holds ``moe_counts``) unless ``moe_capacity`` selects training's
+    capacity-bounded dispatch and its losses.
     """
     tokens = batch["tokens"]
     seq_len = batch.get("seq_len")     # (B,) real row lengths (recurrent
@@ -358,10 +404,14 @@ def forward(params, batch: Dict[str, jax.Array], cfg: ArchConfig,
     if cfg.family == "audio":
         body = _audio_decoder_body(cfg, dims, opt, pins, enc_out)
     else:
-        body = _decoder_body(cfg, dims, opt, pins, seq_len)
+        body = _decoder_body(cfg, dims, opt, pins, seq_len, moe_capacity)
     if opt.remat:
         body = jax.checkpoint(body)
-    (x, aux), caches = jax.lax.scan(body, (x, _zero_aux()), params["layers"])
+    xs = params["layers"]
+    if cfg.mixed_attention:
+        xs = (xs, layer_kinds(cfg, dims.head_dim))
+    (x, aux), caches = jax.lax.scan(body, (x, _zero_aux(cfg, moe_capacity)),
+                                    xs)
 
     with jax.named_scope("lm_head"):
         x = L.rms_norm(x, params["final_norm"].astype(jnp.float32),
@@ -379,7 +429,8 @@ def forward(params, batch: Dict[str, jax.Array], cfg: ArchConfig,
 def loss_fn(params, batch, cfg: ArchConfig, dims: ModelDims,
             opt: FwdOptions = FwdOptions(), pins: L.Pins = L.no_pins,
             moe_loss_weight: float = 0.01, z_loss_weight: float = 1e-3):
-    logits, aux, _ = forward(params, batch, cfg, dims, opt, pins)
+    logits, aux, _ = forward(params, batch, cfg, dims, opt, pins,
+                             moe_capacity=True)
     labels = batch["labels"]
     mask = (labels >= 0).astype(jnp.float32)
     labels_safe = jnp.maximum(labels, 0)

@@ -592,17 +592,21 @@ def _pool_blocks(pool, i, slots):
 
 
 @jax.named_scope("ffn")
-def decode_ffn(blk, x, cfg: ArchConfig, pins) -> jax.Array:
-    """Post-attention FFN sublayer (dense MLP or decode-time MoE)."""
+def decode_ffn(blk, x, cfg: ArchConfig, pins, valid=None):
+    """Post-attention FFN sublayer (dense MLP or decode-time MoE) ->
+    (x, MoE [routed pairs, experts hit] of the ``valid`` rows, or None
+    for a dense MLP)."""
     h = Lmod.rms_norm(x, blk["norm2"].astype(jnp.float32), cfg.norm_eps)
+    counts = None
     if "moe" in blk:
         lead = h.shape[:-1]
-        out = moe_decode(blk["moe"], h.reshape(-1, h.shape[-1]),
-                         top_k=cfg.moe_top_k,
-                         pins=pins).reshape(*lead, -1)
+        out, counts = moe_decode(
+            blk["moe"], h.reshape(-1, h.shape[-1]), top_k=cfg.moe_top_k,
+            valid=None if valid is None else valid.reshape(-1), pins=pins)
+        out = out.reshape(*lead, -1)
     else:
         out = Lmod.mlp(blk["mlp"], h, pins)
-    return x + pins("dec_bd", out)
+    return x + pins("dec_bd", out), counts
 
 
 def decode_cross(blk, x, ck, cv, cfg: ArchConfig, dims: ModelDims, pins
@@ -669,7 +673,7 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
     slot as live.
     """
 
-    def qkv_decode(blk, x, positions):
+    def qkv_decode(blk, x, positions, kind=None):
         B = x.shape[0]
         h = Lmod.rms_norm(x, blk["norm1"].astype(jnp.float32), cfg.norm_eps)
         q = Lmod.linear(blk["attn"]["q"], h).reshape(B, dims.n_heads,
@@ -678,23 +682,29 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
                                                      dims.head_dim)
         v = Lmod.linear(blk["attn"]["v"], h).reshape(B, dims.n_kv,
                                                      dims.head_dim)
-        if cfg.rope_theta > 0:
+        if kind is not None or cfg.rope_theta > 0:
+            rope = () if kind is None else (kind["inv_freq"],
+                                            kind["rope_scale"])
             q = Lmod.apply_rope(q[:, None], positions[:, None],
-                                cfg.rope_theta)[:, 0]
+                                cfg.rope_theta, *rope)[:, 0]
             k = Lmod.apply_rope(k[:, None], positions[:, None],
-                                cfg.rope_theta)[:, 0]
+                                cfg.rope_theta, *rope)[:, 0]
         return q, k, v
 
     sharded = mesh is not None and spec.kv_shards >= 1
     if sharded and part is None:
         raise ValueError("spec.kv_shards >= 1 requires a Partition")
+    if mesh is not None and cfg.mixed_attention:
+        raise NotImplementedError(
+            "the mesh decode paths have no per-layer window or rotary "
+            f"table; serve {cfg.name} without a mesh")
 
     @jax.named_scope("attn")
-    def attn_sublayer(blk, x, kp, vp, i, trans, positions):
+    def attn_sublayer(blk, x, kp, vp, i, trans, positions, kind=None):
         """Attention of layer ``i``; ``kp``/``vp`` are the carried
         ``(L, ...)`` pools, returned with this layer's write done."""
         B = x.shape[0]
-        q, k, v = qkv_decode(blk, x, positions)
+        q, k, v = qkv_decode(blk, x, positions, kind)
         if sharded:
             out, kp, vp = _paged_attn_shard_local(
                 q, k, v, kp, vp, i, trans, positions, spec, part)
@@ -711,12 +721,13 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
             vp = jax.lax.dynamic_update_index_in_dim(vp, vp_l, i, 0)
         else:
             out, kp, vp = _paged_attn_local_ref(
-                q, k, v, kp, vp, i, trans, positions, spec)
+                q, k, v, kp, vp, i, trans, positions, spec,
+                window=None if kind is None else kind["window"])
         o = Lmod.linear(blk["attn"]["o"], out.reshape(B, -1).astype(x.dtype))
         return x + pins("dec_bd", o), kp, vp
 
-    def ffn_sublayer(blk, x):
-        return decode_ffn(blk, x, cfg, pins)
+    def ffn_sublayer(blk, x, act):
+        return decode_ffn(blk, x, cfg, pins, valid=act)
 
     def mamba_sublayer(blk, x, ssm, conv):
         h = Lmod.rms_norm(x, blk["norm1"].astype(jnp.float32), cfg.norm_eps)
@@ -772,21 +783,25 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
             if fam == "audio":
                 xs["ck"] = dstate["cross_k"]
                 xs["cv"] = dstate["cross_v"]
+            if cfg.mixed_attention:
+                xs["kind"] = Tmod.layer_kinds(cfg, dims.head_dim)
 
             def body(carry, xl):
                 x, kp, vp = carry
                 blk = xl["blk"]
                 i = xl["idx"]
                 x, kp, vp = attn_sublayer(blk, x, kp, vp, i, trans,
-                                          positions)
+                                          positions, xl.get("kind"))
                 if fam == "audio":
                     x = cross_sublayer(blk, x, xl["ck"], xl["cv"], None)
-                x = ffn_sublayer(blk, x)
-                return (x, kp, vp), None
+                x, counts = ffn_sublayer(blk, x, act)
+                return (x, kp, vp), counts
 
-            (x, kp, vp), _ = jax.lax.scan(
+            (x, kp, vp), counts = jax.lax.scan(
                 body, (x, dstate["k_pool"], dstate["v_pool"]), xs)
             new_state["k_pool"], new_state["v_pool"] = kp, vp
+            if counts is not None:
+                stats["moe"] = counts.sum(axis=0)
         elif fam == "ssm":
             xs = {"blk": params["layers"], "ssm": dstate["ssm"],
                   "conv": dstate["conv"]}
@@ -828,7 +843,8 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
                     else:
                         x, kp, vp = attn_sublayer(
                             blk["attn"], x, kp, vp, gi, trans, positions)
-                    x = ffn_sublayer(Tmod.hybrid_ffn_select(cfg, blk, i), x)
+                    x, _ = ffn_sublayer(Tmod.hybrid_ffn_select(cfg, blk, i),
+                                        x, act)
                 return (x, kp, vp), {"ssm": jnp.stack(ssm_out),
                                      "conv": jnp.stack(conv_out)}
 
@@ -897,18 +913,19 @@ def make_serve_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
 
 def _paged_attn_local_ref(q, k_new, v_new, kp, vp, i,
                           trans: StepTranslation, pos,
-                          spec: DecodeSpec):
+                          spec: DecodeSpec, window=None):
     """Mesh-free reference used by the engine on one device (G=1, TP=1).
 
     Consumes the pre-resolved ``StepTranslation`` — no translation here.
     Writes the current token into layer ``i`` of the carried pools and
-    attends over that layer's blocks gathered from them.
+    attends over that layer's blocks gathered from them (within
+    ``window`` of the new token, on a sliding-window layer).
     """
     slots = trans.slots[0]                          # (B, nblk)
     kp, vp = _pool_write(kp, vp, i, trans.w_slot[0], pos % spec.block_size,
                          k_new, v_new, trans.w_valid[0])
     o, m, l = paged_attention_blocks(q, _pool_blocks(kp, i, slots),
                                      _pool_blocks(vp, i, slots),
-                                     slots, pos + 1)
+                                     slots, pos + 1, window=window)
     out = (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
     return out, kp, vp

@@ -90,13 +90,14 @@ launcher shards across a pod.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import pickle
 import time
 import warnings
 from collections import defaultdict
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Deque, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +121,9 @@ from .spec_decode import make_spec_decode_step
 # a span costs its enter and exit; names are constants, so no string or
 # kwargs is built per step.
 _span = jax.profiler.TraceAnnotation
+
+# MoE executions kept in ``stats()["moe"]["steps"]``: minutes of serving
+MOE_LOG_STEPS = 16384
 
 
 def _pad_pow2(idx: np.ndarray, fill) -> np.ndarray:
@@ -592,6 +596,17 @@ class Engine:
         # spec commit, prefill first-tokens): the metrics logger's
         # per-step tokens delta and the dashboard tokens/s numerator
         self._tokens_emitted = 0
+        # MoE telemetry, fetched with each step's one device_get: per
+        # program kind ("decode" / "prefill") executions, routed (token,
+        # expert) pairs and distinct experts hit, summed over MoE layers;
+        # ``_moe_log`` keeps the last steps' readings with their fetch
+        # times for window-bounded readers
+        self._moe_totals = {k: {"executions": 0, "routed_pairs": 0,
+                                "experts_hit": 0}
+                            for k in ("decode", "prefill")}
+        self._moe_pending: List[Any] = []
+        self._moe_log: Deque[Tuple[float, str, int, int]] = \
+            collections.deque(maxlen=MOE_LOG_STEPS)
         # request-lifecycle monotone counters (ISSUE 10): explicit
         # cancellations and wall-clock deadline expiries
         self._cancelled = 0
@@ -1535,6 +1550,8 @@ class Engine:
             self.params, self.dstate, batch, jnp.asarray(slots),
             jnp.asarray(slot_ids), jnp.asarray(ctx), jnp.asarray(last_pos),
             sample=any_sampled)
+        if "moe" in pstats:
+            self._moe_pending.append(pstats["moe"])
         out = []
         for i, (req, start, end, final) in enumerate(grp):
             self._ctx_host[slot_ids[i]] = int(ctx[i])
@@ -1610,6 +1627,8 @@ class Engine:
             jnp.asarray(new_slots), jnp.asarray(prefix_slots),
             jnp.asarray(slot_ids), jnp.asarray(ctx), jnp.asarray(pctx),
             jnp.asarray(last_pos), sample=any_sampled)
+        if "moe" in pstats:
+            self._moe_pending.append(pstats["moe"])
         out = []
         for i, (req, start, end, final) in enumerate(grp):
             self._ctx_host[slot_ids[i]] = int(ctx[i])
@@ -1831,6 +1850,9 @@ class Engine:
             pending = self._admit(self.prefill_budget)
         for r, tok in pending:
             fetch[f"p{r.seq_id}"] = tok
+        if self._moe_pending:
+            fetch["moe_prefill"] = self._moe_pending
+            self._moe_pending = []
         live = [self._states[sid] for sid, r in self.requests.items()
                 if not self._states[sid].done
                 and sid not in self._prefilling]
@@ -1879,6 +1901,8 @@ class Engine:
             else:
                 fetch["next"] = tstats["next_token"]
             fetch["ctx"] = self.dstate["ctx_len"]
+            if "moe" in tstats:
+                fetch["moe_decode"] = tstats["moe"]
             want_stats = self._n_attn_layers and self.track_stats
             if want_stats:
                 fetch["in_rest"] = tstats["in_rest"]
@@ -1897,6 +1921,7 @@ class Engine:
         # ---- the step's ONE device->host fetch --------------------------
         with _span("engine.fetch"):
             host = jax.device_get(fetch)
+        self._record_moe(host)
 
         out: Dict[int, int] = {}
         if live:
@@ -1935,6 +1960,19 @@ class Engine:
             if crash is not None:
                 crash(self._step_count, "post")
         return out
+
+    def _record_moe(self, host) -> None:
+        """Add the fetched MoE counters to the totals and the step log."""
+        t = time.perf_counter()
+        for kind, runs in (("prefill", host.get("moe_prefill", ())),
+                           ("decode", [host["moe_decode"]]
+                            if "moe_decode" in host else ())):
+            tot = self._moe_totals[kind]
+            for pairs, hit in runs:
+                tot["executions"] += 1
+                tot["routed_pairs"] += int(pairs)
+                tot["experts_hit"] += int(hit)
+                self._moe_log.append((t, kind, int(pairs), int(hit)))
 
     def _feed_translation_stats(self, live, host, ctx_pre) -> None:
         """Feed the step's translation telemetry back (PTW-cost
@@ -2398,6 +2436,12 @@ class Engine:
             "cancelled": self._cancelled,
             "deadline_expired": self._deadline_expired,
         }
+        if self.cfg.moe_num_experts:
+            # [decode, prefill], as the list-valued counters are emitted
+            c["moe_routed_pairs"] = [self._moe_totals[k]["routed_pairs"]
+                                     for k in ("decode", "prefill")]
+            c["moe_experts_hit"] = [self._moe_totals[k]["experts_hit"]
+                                    for k in ("decode", "prefill")]
         if self.partition is not None:
             c["shard_swap_bytes_out"] = [int(x)
                                          for x in self._shard_swap_out]
@@ -2476,6 +2520,13 @@ class Engine:
             "evictions": int(pc.stats["evictions"]) if pc else 0,
             "cached_blocks": pc.n_entries if pc else 0,
         }
+        if self.cfg.moe_num_experts:
+            # per program kind: executions, routed pairs and experts hit
+            # summed over MoE layers; ``steps`` holds the last
+            # ``MOE_LOG_STEPS`` executions as (fetch time on
+            # ``time.perf_counter``, kind, routed pairs, experts hit)
+            s["moe"] = {k: dict(v) for k, v in self._moe_totals.items()}
+            s["moe"]["steps"] = list(self._moe_log)
         s["per_request"] = {
             sid: {"rsw_hits": st.rsw_hits, "flex_walks": st.flex_walks,
                   "swap_faults": st.swap_faults, "drafted": st.drafted,

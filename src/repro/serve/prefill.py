@@ -74,7 +74,8 @@ from repro.models import (FwdOptions, forward, dense_attention,
 from repro.models import layers as Lmod
 from repro.models.layers import no_pins
 from repro.models.ssm import MambaCache, mamba_forward
-from repro.models.transformer import ModelDims, _ffn, hybrid_ffn_select
+from repro.models.transformer import (ModelDims, _ffn, hybrid_ffn_select,
+                                      layer_kinds)
 from repro.core.partition import Partition
 from repro.dist.sharding import kv_state_specs
 from repro.kernels.paged_attention.ref import (gather_pool_blocks,
@@ -224,6 +225,9 @@ def make_prefill_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
 
     def prefill_step(params, dstate, batch, slots, slot_ids, ctx, last_pos,
                      *, sample=False):
+        if cfg.moe_num_experts and "seq_len" not in batch:
+            # bucket padding stays out of the drop-free MoE
+            batch = {**batch, "seq_len": jnp.where(slot_ids >= 0, ctx, 0)}
         logits, aux, caches = forward(params, batch, cfg, dims, fwd_collect,
                                       pins)
         new_state = dict(dstate)
@@ -253,6 +257,8 @@ def make_prefill_step(cfg: ArchConfig, dims: ModelDims, spec: DecodeSpec,
         last = jnp.take_along_axis(
             logits, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         stats = _first_token_stats(dstate, last, sid, ctx, n_slots, sample)
+        if cfg.moe_num_experts:
+            stats["moe"] = aux["moe_counts"]
         return last, new_state, stats
 
     if not sharded:
@@ -323,12 +329,17 @@ def make_prefix_prefill_step(cfg: ArchConfig, dims: ModelDims,
         raise NotImplementedError(
             "Pallas prefix gather is single-device; the sharded engine "
             "drives the ref path")
+    if cfg.mixed_attention and gather != "exact":
+        raise NotImplementedError(
+            "the paged prefix gather has no per-layer window or rotary "
+            "table; use prefix_gather='exact' for this model")
     opt = fwd
     bs = spec.block_size
     fam = cfg.family
 
     @jax.named_scope("prefix_read")
-    def attn_read(q, k_new, v_new, kp_l, vp_l, prefix_slots, prefix_ctx):
+    def attn_read(q, k_new, v_new, kp_l, vp_l, prefix_slots, prefix_ctx,
+                  window=None):
         B, S, H, hd = q.shape
         KV = k_new.shape[2]
         if gather == "paged":
@@ -388,18 +399,22 @@ def make_prefix_prefill_step(cfg: ArchConfig, dims: ModelDims,
         k_full = jnp.where(sel, gk, ck_g).reshape(B, nblk_buf * bs, KV, hd)
         v_full = jnp.where(sel, gv, cv_g).reshape(B, nblk_buf * bs, KV, hd)
         return dense_attention(q, k_full, v_full, causal=True,
-                               q_offset=prefix_ctx)
+                               q_offset=prefix_ctx, window=window)
 
     @jax.named_scope("attn")
     def attn_sublayer(blk, x, kp_l, vp_l, prefix_slots, positions,
-                      prefix_ctx):
+                      prefix_ctx, kind=None):
         B, S, _ = x.shape
         h = Lmod.rms_norm(x, blk["norm1"].astype(jnp.float32), cfg.norm_eps)
         h = pins("act_full", h)
+        rope = () if kind is None else (kind["inv_freq"],
+                                        kind["rope_scale"])
         q, k, v = Lmod.qkv_project(blk["attn"], h, h, dims.n_heads,
                                    dims.n_kv, dims.head_dim, positions,
-                                   positions, cfg.rope_theta, pins)
-        o = attn_read(q, k, v, kp_l, vp_l, prefix_slots, prefix_ctx)
+                                   positions, cfg.rope_theta, pins,
+                                   rope=rope)
+        o = attn_read(q, k, v, kp_l, vp_l, prefix_slots, prefix_ctx,
+                      None if kind is None else kind["window"])
         o = Lmod.linear(blk["attn"]["o"], o.reshape(B, S, -1))
         return x + pins("act_btd", o), (k, v)
 
@@ -436,20 +451,34 @@ def make_prefix_prefill_step(cfg: ArchConfig, dims: ModelDims,
         # per-row real chunk length: the recurrent mask that makes the
         # pow2 pad tail an exact identity transition of the SSM state
         chunk_len = (ctx - prefix_ctx).astype(jnp.int32)
+        # the chunk's real tokens: bucket padding stays out of the MoE
+        tok_ok = None
+        if cfg.moe_num_experts:
+            tok_ok = ((jnp.arange(S, dtype=jnp.int32)[None, :]
+                       < chunk_len[:, None]) & row_ok[:, None])
+        moe_counts = None
 
         if fam in ("dense", "moe", "vlm"):
             xs = {"blk": params["layers"],
                   "kp": dstate["k_pool"], "vp": dstate["v_pool"]}
+            if cfg.mixed_attention:
+                xs["kind"] = layer_kinds(cfg, dims.head_dim)
 
             def body(x, xl):
                 x, (k, v) = attn_sublayer(xl["blk"], x, xl["kp"], xl["vp"],
                                           prefix_slots, positions,
-                                          prefix_ctx)
-                x, _ = _ffn(xl["blk"], x, cfg, dims, opt, pins)
-                return x, {"k": k, "v": v}
+                                          prefix_ctx, xl.get("kind"))
+                x, aux = _ffn(xl["blk"], x, cfg, dims, opt, pins,
+                              valid=tok_ok)
+                ys = {"k": k, "v": v}
+                if aux is not None:
+                    ys["moe"] = aux["moe_counts"]
+                return x, ys
 
             x, ys = jax.lax.scan(body, x, xs)
             caches = {"k": ys["k"], "v": ys["v"]}
+            if "moe" in ys:
+                moe_counts = ys["moe"].sum(axis=0)
         elif fam == "ssm":
             xs = {"blk": params["layers"],
                   "ssm": dstate["ssm"][:, sid_safe],
@@ -489,7 +518,7 @@ def make_prefix_prefill_step(cfg: ArchConfig, dims: ModelDims,
                             blk["attn"], x, xl["kp"], xl["vp"],
                             prefix_slots, positions, prefix_ctx)
                     x, _ = _ffn(hybrid_ffn_select(cfg, blk, i), x, cfg,
-                                dims, opt, pins)
+                                dims, opt, pins, valid=tok_ok)
                 return x, {"k": k, "v": v, "ssm": jnp.stack(ssm_out),
                            "conv": jnp.stack(conv_out)}
 
@@ -537,6 +566,8 @@ def make_prefix_prefill_step(cfg: ArchConfig, dims: ModelDims,
         last = jnp.take_along_axis(
             logits, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         stats = _first_token_stats(dstate, last, sid, ctx, n_slots, sample)
+        if moe_counts is not None:
+            stats["moe"] = moe_counts
         return last, new_state, stats
 
     if not sharded:

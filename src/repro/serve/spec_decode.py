@@ -122,6 +122,10 @@ def make_spec_decode_step(cfg: ArchConfig, dims: ModelDims,
             "path (ROADMAP) drives the non-speculative step")
     if sharded and part is None:
         raise ValueError("spec.kv_shards >= 1 requires a Partition")
+    if cfg.mixed_attention:
+        raise NotImplementedError(
+            "the speculative verify step has no per-layer window or rotary "
+            f"table; serve {cfg.name} without spec_decode")
     if cfg.family not in SPEC_FAMILIES:
         raise ValueError(
             f"speculative decode does not support family {cfg.family!r} "
@@ -240,7 +244,7 @@ def make_spec_decode_step(cfg: ArchConfig, dims: ModelDims,
             if fam == "audio":
                 x = decode_cross(blk, x, xl["ck"], xl["cv"], cfg, dims,
                                  pins)
-            x = decode_ffn(blk, x, cfg, pins)
+            x, _ = decode_ffn(blk, x, cfg, pins)
             return (x, kp, vp), None
 
         (x, kp, vp), _ = jax.lax.scan(

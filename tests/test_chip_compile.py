@@ -84,3 +84,17 @@ def test_flash_attention_compiles(one_chip):
                  q, k, v, causal=True, interpret=False),
              one_chip, ((1, S, H, D), jnp.bfloat16),
              ((1, S, KV, D), jnp.bfloat16), ((1, S, KV, D), jnp.bfloat16))
+
+
+def test_dropless_moe_compiles_to_ragged_dot(one_chip):
+    """Mellum2's routed FFN at its widths (64 experts of 896 over d 2304,
+    top 8) for 2,048 prompt tokens: the grouped matmul is the TPU's
+    ragged dot, not a dense product over every expert."""
+    from repro.models.moe import moe_dropless
+    E, Dm, F, T = 64, 2304, 896, 2048
+    sd = lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+    p = {"router": {"w": sd((Dm, E))}, "gate": sd((E, Dm, F)),
+         "up": sd((E, Dm, F)), "down": sd((E, F, Dm))}
+    compiled = jax.jit(lambda p, x: moe_dropless(p, x, top_k=8)).lower(
+        p, sd((T, Dm))).compile()
+    assert "ragged-dot" in compiled.as_text()
