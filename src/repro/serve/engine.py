@@ -160,6 +160,21 @@ def _scatter_delta(tar, sf, flex, sets_idx, tar_rows, sf_rows, flex_idx,
     return tar, sf, flex
 
 
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_pool_slots(k_pool, v_pool, dst, src=None, kv=None):
+    """Write slots ``dst`` of both KV pools in place, as ONE dispatch that
+    donates the pools: from slots ``src`` of the same pools (slot
+    migrations; the gather reads the pre-write contents) or from ``kv``,
+    the stacked ``(2, L, n, ...)`` blocks of a resumed sequence.  An
+    eager ``pool.at[].set`` would write a whole new pool per call.
+    Indices are pow2-padded by the caller (bounded executable set)."""
+    if src is not None:
+        k_rows, v_rows = k_pool[:, src], v_pool[:, src]
+    else:
+        k_rows, v_rows = kv[0], kv[1]
+    return k_pool.at[:, dst].set(k_rows), v_pool.at[:, dst].set(v_rows)
+
+
 # ------------------------------------------------------------- request API
 
 @dataclasses.dataclass(frozen=True)
@@ -629,26 +644,34 @@ class Engine:
             pass                       # slotted/frozen scheduler: skip
         self.fwd = FwdOptions(attn_impl=config.attn_impl, dtype=dtype,
                               collect_cache=True)
+        # Every step program takes the decode state (argument 1) as a
+        # donated argument: its outputs alias the input buffers, so a step
+        # updates the KV pools in place instead of first copying both.
+        # The engine is the state's only holder and rebinds ``dstate`` to
+        # each step's output; the pre-step arrays are deleted.
         # ``sample`` is static: at most two cached executables (all-greedy
         # / any-sampled); the all-greedy one is the pre-sampling argmax
         # hot path, with no sort/softmax/gumbel in the trace
         self._serve_step = jax.jit(make_serve_step(
             cfg, self.dims, self.spec, mesh=self.mesh, dtype=dtype,
             part=self.partition),
-            static_argnames=("sample",))
+            static_argnames=("sample",), donate_argnums=(1,))
         # one jitted callable; XLA re-specializes per (bucket_B, bucket_S)
         # — both power-of-two padded, so the executable set is bounded
         self._prefill_step = jax.jit(make_prefill_step(
             cfg, self.dims, self.spec, mesh=self.mesh, fwd=self.fwd,
             part=self.partition),
-            static_argnames=("sample",))
+            static_argnames=("sample",), donate_argnums=(1,))
         # prefix-KV chunk step: chunks k > 0 forward only their own tokens
         # and read the prefix from the pool (shapes keyed additionally by
         # the pow2 prefix-buffer width — still a bounded set)
         self._prefix_step = jax.jit(make_prefix_prefill_step(
             cfg, self.dims, self.spec, mesh=self.mesh, fwd=self.fwd,
             part=self.partition),
-            static_argnames=("sample",))
+            static_argnames=("sample",), donate_argnums=(1,))
+        # pool writes outside the step programs (``_write_pool``):
+        # dispatches of ``_write_pool_slots`` and the slots they wrote
+        self._pool_writes = {"dispatches": 0, "slots": 0}
         # ---- speculative decoding (serve/spec_decode.py) ----------------
         sd = config.spec_decode
         if sd is True:
@@ -677,7 +700,7 @@ class Engine:
                     cfg, self.dims, self.spec, self.spec_K, mesh=self.mesh,
                     dtype=dtype, ngram=config.spec_ngram,
                     part=self.partition),
-                    static_argnames=("sample",))
+                    static_argnames=("sample",), donate_argnums=(1,))
                 # per-slot token history the in-graph drafter matches
                 # against (prompt scattered at admission, accepted tokens
                 # appended in-graph; -1 = unknown)
@@ -1314,16 +1337,9 @@ class Engine:
             # re-resolve AFTER the copies: a later block's allocation may
             # have evict-migrated an earlier one within this same resume,
             # so the scatter must target where each block lives now
-            dh = np.asarray([m.lookup(sid, b)[0] for b, _ in rec.blocks],
-                            np.int32)
-            if self.partition is not None:
-                dh = self.partition.phys(dh)
-            dst = jnp.asarray(dh)
-            kv = jnp.asarray(rec.kv)
-            self.dstate["k_pool"] = \
-                self.dstate["k_pool"].at[:, dst].set(kv[0])
-            self.dstate["v_pool"] = \
-                self.dstate["v_pool"].at[:, dst].set(kv[1])
+            self._write_pool(
+                np.asarray([m.lookup(sid, b)[0] for b, _ in rec.blocks],
+                           np.int32), kv=rec.kv)
         for key, row in rec.rows.items():
             if key == "hist":
                 self.dstate["hist"] = \
@@ -1785,22 +1801,36 @@ class Engine:
         pairs = [(d, s) for d, s in root.items() if d != s]
         if not pairs:
             return
-        # pad to pow2 by duplicating the first pair (duplicate scatter
+        self._write_pool(np.asarray([d for d, _ in pairs], np.int32),
+                         src=np.asarray([s for _, s in pairs], np.int32))
+
+    def _write_pool(self, dst: np.ndarray, src: Optional[np.ndarray] = None,
+                    kv: Optional[np.ndarray] = None) -> None:
+        """Write logical pool slots ``dst`` of both KV pools in place
+        (``_write_pool_slots``): copies from slots ``src``, or the host
+        blocks ``kv`` of shape ``(2, L, len(dst), ...)``."""
+        n = dst.size
+        self._pool_writes["dispatches"] += 1
+        self._pool_writes["slots"] += n
+        # pad to pow2 by duplicating the first entry (duplicate scatter
         # index with the same value — benign): bounded scatter shapes
-        dst = _pad_pow2(np.asarray([d for d, _ in pairs], np.int32),
-                        pairs[0][0])
-        src = _pad_pow2(np.asarray([s for _, s in pairs], np.int32),
-                        pairs[0][1])
+        dst = _pad_pow2(dst, dst[0])
+        if src is not None:
+            src = _pad_pow2(src, src[0])
+        if kv is not None and dst.size > n:
+            kv = np.concatenate(
+                [kv, np.repeat(kv[:, :, :1], dst.size - n, axis=2)], axis=2)
         if self.partition is not None:
             # manager slots are logical; the sharded pool is laid out in
             # shard-contiguous physical order.  GSPMD turns this into the
             # exact cross-shard data movement.
             dst = self.partition.phys(dst)
-            src = self.partition.phys(src)
-        dst, src = jnp.asarray(dst), jnp.asarray(src)
-        for key in ("k_pool", "v_pool"):
-            pool = self.dstate[key]
-            self.dstate[key] = pool.at[:, dst].set(pool[:, src])
+            if src is not None:
+                src = self.partition.phys(src)
+        self.dstate["k_pool"], self.dstate["v_pool"] = _write_pool_slots(
+            self.dstate["k_pool"], self.dstate["v_pool"], jnp.asarray(dst),
+            src=None if src is None else jnp.asarray(src),
+            kv=None if kv is None else jnp.asarray(kv))
 
     def step(self) -> Dict[int, int]:
         """One engine step: admit under the prefill budget, then decode
@@ -2296,7 +2326,9 @@ class Engine:
         # pending slot migrations must land first so the fetched pool
         # bytes agree with the manager's (pickled) post-copy slot map
         self._apply_copies()
-        dstate = {k: np.asarray(v) for k, v in jax.device_get(
+        # ``np.array`` copies: on the CPU a fetched array can be a view of
+        # the device buffer, which the next step's donation must not meet
+        dstate = {k: np.array(v) for k, v in jax.device_get(
             {k: v for k, v in self.dstate.items()
              if k not in ("tar", "sf", "flex")}).items()}
         now = time.perf_counter()
@@ -2384,15 +2416,15 @@ class Engine:
                 st.admitted_at += shift
         # device state: put the fetched arrays back (with the mesh's
         # shardings when sharded — specs computed from the CURRENT
-        # dstate before overwriting, the key sets are identical)
-        if self.mesh is not None:
-            specs = kv_state_specs(self.dstate, self.spec)
-            for k, v in snap.dstate.items():
-                self.dstate[k] = jax.device_put(
-                    v, NamedSharding(self.mesh, specs[k]))
-        else:
-            for k, v in snap.dstate.items():
-                self.dstate[k] = jnp.asarray(v)
+        # dstate before overwriting, the key sets are identical) as
+        # buffers the engine owns: a zero-copy view of the snapshot's
+        # numpy array could not be donated to the next step
+        specs = (kv_state_specs(self.dstate, self.spec)
+                 if self.mesh is not None else None)
+        for k, v in snap.dstate.items():
+            self.dstate[k] = jnp.array(
+                v, copy=True, device=(None if specs is None else
+                                      NamedSharding(self.mesh, specs[k])))
         # translation mirrors: rebuilt from the restored host tables via
         # the one true sync path (also clears the manager's dirty set)
         self._synced_full = False
@@ -2520,6 +2552,9 @@ class Engine:
             "evictions": int(pc.stats["evictions"]) if pc else 0,
             "cached_blocks": pc.n_entries if pc else 0,
         }
+        # KV pool writes outside the step programs (slot migrations and
+        # resume scatters): in-place dispatches and the slots written
+        s["pool_writes"] = dict(self._pool_writes)
         if self.cfg.moe_num_experts:
             # per program kind: executions, routed pairs and experts hit
             # summed over MoE layers; ``steps`` holds the last

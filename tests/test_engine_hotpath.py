@@ -232,8 +232,10 @@ def test_batched_copies_match_sequential(small_engine_factory):
     rng = np.random.RandomState(7)
     kp = rng.randn(*shape).astype(np.float32)
     vp = rng.randn(*shape).astype(np.float32)
-    eng.dstate["k_pool"] = jnp.asarray(kp)
-    eng.dstate["v_pool"] = jnp.asarray(vp)
+    # buffers of the engine's own (``jnp.array`` copies): the pools are
+    # donated to the slot-copy program, which must not meet a view of kp
+    eng.dstate["k_pool"] = jnp.array(kp)
+    eng.dstate["v_pool"] = jnp.array(vp)
     # includes a chain 3->5->9 and an overwrite of dst 11
     copies = [(3, 5), (5, 9), (2, 11), (4, 11), (0, 1)]
     ref_k, ref_v = kp.copy(), vp.copy()
